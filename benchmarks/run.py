@@ -51,6 +51,7 @@ def main(argv=None) -> int:
                             fig5_metric_learning, remark1_alpha,
                             table1_bnn)
     from benchmarks.common import write_json
+    from repro.launch.cache import enable_compile_cache
 
     modules = [
         ("fig1", fig1_variance), ("fig2_3", fig2_3_gaussian),
@@ -67,6 +68,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of benchmark names")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.only:
         wanted = set(args.only.split(","))
         unknown = wanted - {name for name, _ in modules}

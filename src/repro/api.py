@@ -564,11 +564,23 @@ def fit_bank_local_sgld(log_lik_fn: LogLikFn, shard_data: PyTree,
     private helper in launch/train.py). Works on any parameter pytree;
     ``kind='scalar'`` fits per-tensor isotropic Gaussians from the second
     half of each local trace, ``kind='diag'`` per-dimension ones (flat
-    vector params only)."""
+    vector params only).
+
+    Clients are fitted one after another (``lax.map``), each reduced to
+    its moments before the next starts: device memory holds one client's
+    trace, not S of them — at billion-parameter widths S stacked traces
+    do not fit one chip."""
     leaf = jax.tree.leaves(shard_data)[0]
     S, n_s = leaf.shape[0], leaf.shape[1]
+    if kind == "diag":
+        flat = jax.tree.leaves(theta0)
+        assert len(flat) == 1 and flat[0].ndim == 1, \
+            "diag fits need flat-vector parameters"
+    elif kind != "scalar":
+        raise ValueError(kind)
 
-    def local_sgld(data_s, k):
+    def local_fit(theta0, data_s, k):
+
         def body(theta, kk):
             k1, k2 = jax.random.split(kk)
             idx = jax.random.randint(k1, (minibatch,), 0, n_s)
@@ -582,26 +594,24 @@ def fit_bank_local_sgld(log_lik_fn: LogLikFn, shard_data: PyTree,
                    + jnp.sqrt(step_size)
                    * jax.random.normal(nk, t.shape, t.dtype)
                    for t, gg, nk in zip(leaves, gl, ks)]
-            theta = jax.tree.unflatten(tdef, new)
-            return theta, theta
+            return jax.tree.unflatten(tdef, new)
 
-        _, trace = jax.lax.scan(body, theta0,
-                                jax.random.split(k, fit_steps))
-        # keep the second half of the trace (burn-in discarded)
-        return jax.tree.map(lambda t: t[fit_steps // 2:], trace)
+        # burn-in (the first half) is run without keeping its states
+        ks = jax.random.split(k, fit_steps)
+        burn = fit_steps // 2
+        theta = jax.lax.fori_loop(0, burn, lambda i, t: body(t, ks[i]),
+                                  theta0)
+        _, trace = jax.lax.scan(lambda t, kk: (body(t, kk),) * 2, theta,
+                                ks[burn:])
+        if kind == "scalar":
+            # per-tensor isotropic fit
+            return fit_scalar_tree(trace, jitter=lam_floor)
+        tr = jax.tree.leaves(trace)[0]
+        return tr.mean(0), 1.0 / (tr.var(0) + lam_floor)
 
-    traces = jax.jit(jax.vmap(local_sgld))(shard_data,
-                                           jax.random.split(key, S))
-    if kind == "scalar":
-        # per-shard per-tensor isotropic fits; vmap keeps the shard axis
-        means, precs = jax.vmap(
-            lambda tr: fit_scalar_tree(tr, jitter=lam_floor))(traces)
-        return make_bank(means, precs, "scalar")
-    if kind == "diag":
-        flat = jax.tree.leaves(traces)
-        assert len(flat) == 1 and flat[0].ndim == 3, \
-            "diag fits need flat-vector parameters"
-        mu = flat[0].mean(1)
-        precs = 1.0 / (flat[0].var(1) + lam_floor)
-        return make_bank(mu, precs, "diag")
-    raise ValueError(kind)
+    # theta0 is an argument, not a closure: closed over, its whole
+    # parameter tree would be baked into the program as constants
+    means, precs = jax.jit(lambda th, d, ks: jax.lax.map(
+        lambda a: local_fit(th, *a), (d, ks)))(
+            theta0, shard_data, jax.random.split(key, S))
+    return make_bank(means, precs, kind)

@@ -34,7 +34,7 @@ block through the PACKED single-launch executor (PR 2): the entire
 parameter pytree of the block lives in one chain-major
 ``(C * rows_total, 128)`` buffer (``kernels.ops.PackedChains``), packed
 ONCE per run, and every step issues exactly ONE ``pallas_call`` covering
-all leaves of all chains via a static segment table. ``packed=False``
+all leaves of all chains via static per-leaf block counts. ``packed=False``
 falls back to the PR 1 per-leaf chain-batched entry
 (``kernels.ops.fused_update_chains_tree`` — one ``pallas_call`` per leaf
 per step).
@@ -98,7 +98,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import SamplerConfig
@@ -353,11 +352,14 @@ def _perm_sids_slice(k_assign: jax.Array, num_shards: int, start,
 
 
 def pack_bank(layout: kops.PackedChains, bank: Optional[SurrogateBank]):
-    """SurrogateBank -> packed operands for the single-launch round body.
+    """SurrogateBank -> operands for the single-launch round body.
 
-    Shared (global) surrogate operands are packed ONCE here — per-round
-    work is only the ``[sids]`` row gather in the round body. Per-shard
-    stacks keep a leading S axis: (S, rows_total, 128).
+    The shared (global) surrogate is packed ONCE here. Per-client means
+    (and diag precisions) stay in the bank's storage dtype with their
+    leading S axis: each round packs only the C resident clients' rows
+    (``resident_surrogates``), so the device never holds an fp32 copy of
+    all S clients — at billion-parameter widths that copy alone is
+    S x 4 bytes per parameter.
     """
     if bank is None:
         return None
@@ -365,16 +367,13 @@ def pack_bank(layout: kops.PackedChains, bank: Optional[SurrogateBank]):
         return {
             "mu_g": layout.pack_shared(bank.global_.mean),
             "lam_g": layout.pack_shared(bank.global_.prec),
-            "means": layout.pack(bank.means).reshape(
-                -1, layout.rows_total, kops.LANE),
-            "precs": layout.pack(bank.precs).reshape(
-                -1, layout.rows_total, kops.LANE),
+            "means": bank.means,
+            "precs": bank.precs,
         }
     if bank.kind == "scalar":
         return {
             "mu_g": layout.pack_shared(bank.global_.mean),
-            "means": layout.pack(bank.means).reshape(
-                -1, layout.rows_total, kops.LANE),
+            "means": bank.means,
             # per-leaf scalar precisions ride in the (C, L, 8) scalar rows
             "lam_g_leaf": jnp.stack([
                 jnp.asarray(p, jnp.float32)
@@ -384,6 +383,15 @@ def pack_bank(layout: kops.PackedChains, bank: Optional[SurrogateBank]):
                 for p in jax.tree.leaves(bank.precs)], axis=1),
         }
     raise ValueError(bank.kind)
+
+
+def resident_surrogates(layout: kops.PackedChains, pbank, sids):
+    """Packed (C * rows_total, 128) resident-client operands for chains at
+    clients ``sids``: (mu_s, lam_s), lam_s None for a 'scalar' bank."""
+    def take(tree):
+        return layout.pack(jax.tree.map(lambda m: m[sids], tree))
+    return (take(pbank["means"]),
+            take(pbank["precs"]) if "precs" in pbank else None)
 
 
 def make_packed_round_fn(log_lik_fn: LogLikFn, cfg: SamplerConfig,
@@ -398,11 +406,11 @@ def make_packed_round_fn(log_lik_fn: LogLikFn, cfg: SamplerConfig,
 
     State is ``(packed, thetas)`` — or ``(packed, momenta_packed, thetas)``
     for ``dynamics='sghmc'``, the momenta riding a SECOND chain-major
-    buffer over the same segment table: the packed buffers are
+    buffer over the same layout: the packed buffers are
     authoritative; the unpacked pytree mirror feeds the gradient pass and
     trace collection, so the scan body contains NO pad/ravel work — leaf
     gradients are written into the packed gradient buffer by static
-    update-slices, and the only per-round (not per-step) work is gathering
+    update-slices, and the only per-round (not per-step) work is packing
     the resident-client surrogate rows and prebuilding the scalar rows.
     Non-fp32 leaves quantize back to their storage dtype after every step
     (``layout.quantize``, identity for all-fp32 trees), replaying the
@@ -433,12 +441,11 @@ def make_packed_round_fn(log_lik_fn: LogLikFn, cfg: SamplerConfig,
         elif bank_kind == "diag":
             variant = "diag"
             mu_g, lam_gp = pbank["mu_g"], pbank["lam_g"]
-            mu_s = pbank["means"][sids].reshape(-1, kops.LANE)
-            lam_sp = pbank["precs"][sids].reshape(-1, kops.LANE)
+            mu_s, lam_sp = resident_surrogates(layout, pbank, sids)
         elif bank_kind == "scalar":
             variant = "scalar"
             mu_g = pbank["mu_g"]
-            mu_s = pbank["means"][sids].reshape(-1, kops.LANE)
+            mu_s, _ = resident_surrogates(layout, pbank, sids)
             lam_g_leaf = pbank["lam_g_leaf"]
             lam_s_leaf = pbank["lam_s_leaf"][sids]
         else:
@@ -885,7 +892,7 @@ class MeshChainEngine:
                 if hmc:
                     th_c, r_c = chains
                     # the momenta ride a SECOND chain-major buffer over
-                    # the SAME segment table (their own seed stream is
+                    # the SAME packed layout (their own seed stream is
                     # the per-step noise draw routed by seed BlockSpecs)
                     state = (layout.pack(th_c), layout.pack(r_c), th_c)
                 else:
@@ -1351,11 +1358,11 @@ class MeshChainEngine:
         if tel is not None:
             # metric rows are chain-major (C, R): sharded like the trace
             out_specs = out_specs + (cspec,)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             block, mesh=self.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check_rep=False)
+            check_vma=False)
         fn = jax.jit(mapped, donate_argnums=(1,))
         self._executors[cache_key] = fn
         return fn
@@ -1373,9 +1380,9 @@ class MeshChainEngine:
                 k[0], S, jax.lax.axis_index("data") * per, per,
                 n_total=n_chains)
 
-        return shard_map(
+        return jax.shard_map(
             block, mesh=self.mesh, in_specs=(P(),),
-            out_specs=P("data"), check_rep=False)(k_assign[None])
+            out_specs=P("data"), check_vma=False)(k_assign[None])
 
     # -- server-side loop --------------------------------------------------
 
@@ -1519,7 +1526,7 @@ class MeshChainEngine:
             # broadcast with theta0 otherwise (same expression either way)
             theta0 = (theta0, init_momentum(theta0))
         # the packed layout is built from the PARAMETER pytree alone: the
-        # sghmc momenta share its structure (and hence its segment table)
+        # sghmc momenta share its structure (and hence its packed layout)
         ex_theta = theta0[0] if self.dynamics == "sghmc" else theta0
         layout = self._layout_for(
             jax.tree.map(lambda t: t[0], ex_theta) if stacked else ex_theta)
@@ -1582,8 +1589,7 @@ class MeshChainEngine:
                 collect_every=collect_every, collect=collect,
                 layout=layout, federation=fed, fedc=fedc, take=take)
 
-        typed_key = hasattr(jax.dtypes, "prng_key") and jnp.issubdtype(
-            key.dtype, jax.dtypes.prng_key)
+        typed_key = jnp.issubdtype(key.dtype, jax.dtypes.prng_key)
 
         def snap_payload(trace_now):
             """The FULL scan carry, real-chain rows only (mesh padding is
@@ -1889,7 +1895,7 @@ def refresh_bank_mesh(log_lik_fn: LogLikFn, shard_data: PyTree,
     m_size = mesh.shape["model"]
     assert S % m_size == 0, (S, m_size)
 
-    def one_shard(data_s, n_s):
+    def one_shard(theta, data_s, n_s):
         # Per-example scores in BATCHED gradient passes: each lax.map step
         # vmaps grad over a whole chunk of examples (gathered by index)
         # instead of a dynamic_slice-of-1 per example. Index chunks pad up
@@ -1915,14 +1921,17 @@ def refresh_bank_mesh(log_lik_fn: LogLikFn, shard_data: PyTree,
                     - gsum * gsum / n_s)
         return gsum, centered
 
-    def block(data_blk, n_blk):
-        return jax.vmap(one_shard)(data_blk, n_blk)
+    def block(theta, data_blk, n_blk):
+        return jax.vmap(one_shard, in_axes=(None, 0, 0))(
+            theta, data_blk, n_blk)
 
-    b, fisher = jax.jit(shard_map(
+    # theta is an explicit (replicated) operand, not a closure: a committed
+    # input closed over by shard_map would carry its outer mesh inside
+    b, fisher = jax.jit(jax.shard_map(
         block, mesh=mesh,
-        in_specs=(P("model"), P("model")),
+        in_specs=(P(), P("model"), P("model")),
         out_specs=(P("model"), P("model")),
-        check_rep=False))(shard_data, n_arr)
+        check_vma=False))(theta, shard_data, n_arr)
     precs = jnp.maximum(fisher, 0.0) + jitter
     mus = theta[None] + b / precs
     return make_bank(mus, precs, "diag")

@@ -36,6 +36,7 @@ ops.py handles ravel / pad / unpad and per-tensor seeds.
 from __future__ import annotations
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -65,69 +66,80 @@ def _mix(h: jax.Array) -> jax.Array:
     return h
 
 
+def _u24_to_f32(u: jax.Array) -> jax.Array:
+    return u.astype(jnp.int32).astype(jnp.float32)
+
+
 def _gaussian_noise(seed: jax.Array, idx: jax.Array) -> jax.Array:
     """Standard normal per element via two hash streams + Box-Muller.
     ``idx``: uint32 global element indices; ``seed``: uint32 scalar."""
     h1 = _mix(idx * jnp.uint32(2) + jnp.uint32(1) + seed * jnp.uint32(0x9E3779B9))
     h2 = _mix(idx * jnp.uint32(2) + seed * jnp.uint32(0x85EBCA77))
-    # 24-bit mantissas -> u in (0, 1); u1 strictly > 0 for the log
-    u1 = (h1 >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / (1 << 24)) \
+    # 24-bit mantissas -> u in (0, 1); u1 strictly > 0 for the log. Mosaic
+    # has no uint32 -> f32 cast; the values are < 2^24, so the hop through
+    # int32 is exact and the stream stays bit-identical to ref.py.
+    u1 = _u24_to_f32(h1 >> jnp.uint32(8)) * (1.0 / (1 << 24)) \
         + (0.5 / (1 << 24))
-    u2 = (h2 >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / (1 << 24))
+    u2 = _u24_to_f32(h2 >> jnp.uint32(8)) * (1.0 / (1 << 24))
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     return r * jnp.cos((2.0 * jnp.pi) * u2)
 
 
-def _global_idx(block_rows: int, blocks_per_chain: int) -> jax.Array:
-    """uint32 element index WITHIN the current chain's parameter vector.
-
-    The grid is chain-major: blocks [c*bpc, (c+1)*bpc) belong to chain c, so
-    the in-chain block index is ``pid % blocks_per_chain``. With one chain
-    (bpc == grid size) this reduces to the global index — bit-identical to
-    the original single-chain kernel.
-    """
-    pid = pl.program_id(0)
-    base = ((pid % blocks_per_chain) * block_rows * LANE).astype(jnp.uint32)
-    row = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, LANE), 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, LANE), 1)
-    return base + row * jnp.uint32(LANE) + col
-
-
 def _drift(variant, sc, th, g, sur):
     """The shared FSGLD drift: prior + scaled minibatch gradient
-    (+ conducive term for the surrogate variants)."""
-    base = -sc[0, S_PRIOR] * th + sc[0, S_SCALE] * g
+    (+ conducive term for the surrogate variants). ``sc(j)`` reads scalar
+    column j of the current (chain, leaf) row."""
+    base = -sc(S_PRIOR) * th + sc(S_SCALE) * g
     if variant == "plain":
         return base
     if variant == "scalar":
         mg, ms = sur
-        cond = sc[0, S_LAMG] * (mg - th) \
-            - (sc[0, S_LAMS] / sc[0, S_FS]) * (ms - th)
+        cond = sc(S_LAMG) * (mg - th) \
+            - (sc(S_LAMS) / sc(S_FS)) * (ms - th)
     else:  # diag
         mg, ms, lg, ls = sur
-        cond = lg * (mg - th) - (ls / sc[0, S_FS]) * (ms - th)
-    return base + sc[0, S_ALPHA] * cond
+        cond = lg * (mg - th) - (ls / sc(S_FS)) * (ms - th)
+    return base + sc(S_ALPHA) * cond
 
 
-def _make_kernel(variant: str, dynamics: str, *, block_rows: int, bpc: int,
-                 packed: bool):
+def _locate(pid, leaf_blocks: tuple):
+    """Grid step -> (chain, leaf, leaf's first block). The grid walks
+    ``chains * bpc`` blocks, chain major; the leaf comes from L scalar
+    compares against the STATIC block counts, so no per-block table has to
+    fit in SMEM (a real model's table outgrows its 1 MiB)."""
+    bpc = sum(leaf_blocks)
+    blk = pid % bpc
+    leaf, start = 0, 0
+    for first in itertools.accumulate(leaf_blocks[:-1]):
+        past = blk >= first
+        leaf = leaf + past.astype(jnp.int32)
+        start = jnp.where(past, first, start)
+    return pid // bpc, leaf, start
+
+
+def _make_kernel(variant: str, dynamics: str, *, block_rows: int,
+                 leaf_blocks: tuple):
     """Kernel body for one (drift variant, dynamics, layout) cell.
 
-    Ref order: [seg, base,] seed, scalars, theta, [momentum,] g,
-    [surrogate operands...], theta_out[, momentum_out]. The langevin cells
-    reproduce the original per-dynamics kernels expression-for-expression,
-    so noise and rounding are unchanged.
+    ``leaf_blocks[l]`` is the number of (block_rows, 128) blocks leaf l
+    owns in each chain's segment (one leaf of ``bpc`` blocks for the
+    per-leaf launcher). Each element keeps its in-leaf index, so the noise
+    stream is bit-identical to the per-leaf kernel and to ref.py.
+
+    Ref order: seed, scalars, theta, [momentum,] g, [surrogate
+    operands...], theta_out[, momentum_out]. ``seed`` (1, 1, 1) and
+    ``scalars`` (1, 1, SCALAR_COLS) are the SMEM rows of the block's
+    (chain, leaf). The langevin cells reproduce the original per-dynamics
+    kernels expression-for-expression, so noise and rounding are
+    unchanged.
     """
     n_sur = _N_SUR[variant]
     momentum = dynamics == "sghmc"
+    bpc = sum(leaf_blocks)
 
-    def kernel(*refs):
-        if packed:
-            _seg_ref, base_ref, seed_ref, sc_ref = refs[:4]
-            refs = refs[4:]
-        else:
-            seed_ref, sc_ref = refs[:2]
-            refs = refs[2:]
+    def kernel(seed_ref, sc_ref, *refs):
+        pid = pl.program_id(0)
+        _, _, start = _locate(pid, leaf_blocks)
         th_ref = refs[0]
         r_ref = refs[1] if momentum else None
         k = 2 if momentum else 1
@@ -136,31 +148,26 @@ def _make_kernel(variant: str, dynamics: str, *, block_rows: int, bpc: int,
                for i in range(n_sur)]
         outs = refs[k + 1 + n_sur:]
 
-        sc = sc_ref[0] if packed else sc_ref[...]     # (1, SCALAR_COLS)
+        def sc(j):
+            return sc_ref[0, 0, j]
+
         th = th_ref[...].astype(jnp.float32)
         g = g_ref[...].astype(jnp.float32)
         drift = _drift(variant, sc, th, g, sur)
 
-        if packed:
-            # in-leaf element index from the prefetched segment table:
-            # keeps the noise stream bit-identical to the per-leaf kernel
-            seed = seed_ref[0, 0]
-            base = base_ref[pl.program_id(0) % bpc].astype(jnp.uint32)
-            row = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, LANE), 0)
-            col = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, LANE), 1)
-            idx = base + row * jnp.uint32(LANE) + col
-        else:
-            seed = seed_ref[0]
-            idx = _global_idx(block_rows, bpc)
-        xi = _gaussian_noise(seed, idx)
+        base = ((pid % bpc - start) * (block_rows * LANE)).astype(jnp.uint32)
+        row = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, LANE), 0)
+        col = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, LANE), 1)
+        idx = base + row * jnp.uint32(LANE) + col
+        xi = _gaussian_noise(seed_ref[0, 0, 0], idx)
 
-        h = sc[0, S_H]
+        h = sc(S_H)
         if dynamics == "langevin":
-            sig = jnp.sqrt(h * sc[0, S_TEMP])
+            sig = jnp.sqrt(h * sc(S_TEMP))
             outs[0][...] = th + (h * 0.5) * drift + sig * xi
         else:
-            a = sc[0, S_FRIC]
-            noise_sig = jnp.sqrt(2.0 * a * sc[0, S_TEMP])
+            a = sc(S_FRIC)
+            noise_sig = jnp.sqrt(2.0 * a * sc(S_TEMP))
             r = r_ref[...].astype(jnp.float32)
             r_new = (1.0 - a) * r + h * drift \
                 + (noise_sig * jnp.sqrt(h)) * xi
@@ -203,8 +210,8 @@ def fsgld_update_2d(theta2d: jax.Array, g2d: jax.Array, seed: jax.Array,
     CHAIN-BATCHED mode (``chains`` > 1): the leading ``rows`` axis is
     chain-major — rows [c*rows_c, (c+1)*rows_c) hold chain c's parameters
     (rows_c = rows / chains). Per-chain operands (theta, r, g, mu_s, lam_s)
-    are full-height; per-chain *scalars* and *seeds* are selected by the
-    BlockSpec index map ``i // bpc`` and SHARED operands (mu_g, lam_g — the
+    are full-height; per-chain *scalars* and *seeds* are read from SMEM
+    by the block's chain ``i // bpc`` and SHARED operands (mu_g, lam_g — the
     global surrogate, identical for every chain) are (rows_c, 128) and
     re-read per chain via ``i % bpc``, so one pallas_call covers the whole
     chain block in a single HBM pass with no broadcast materialisation.
@@ -218,37 +225,10 @@ def fsgld_update_2d(theta2d: jax.Array, g2d: jax.Array, seed: jax.Array,
     br = min(block_rows, rows_c)
     assert rows_c % br == 0, (rows_c, br)
     bpc = rows_c // br  # blocks per chain
-    grid = (rows // br,)
-
-    tile = pl.BlockSpec((br, LANE), lambda i: (i, 0))
-    shared_tile = pl.BlockSpec((br, LANE), lambda i: (i % bpc, 0))
-    scalar_spec = pl.BlockSpec((1, SCALAR_COLS), lambda i: (i // bpc, 0))
-    seed_spec = pl.BlockSpec((1,), lambda i: (i // bpc,))
-
-    kernel = _make_kernel(variant, dynamics, block_rows=br, bpc=bpc,
-                          packed=False)
-    sur_ops, sur_specs = _variant_ops(variant, mu_g, mu_s, lam_g, lam_s,
-                                      tile, shared_tile)
-    if dynamics == "sghmc":
-        assert r2d is not None and r2d.shape == theta2d.shape
-        ops = [theta2d, r2d, g2d] + sur_ops
-        specs = [tile, tile, tile] + sur_specs
-        out_specs = (tile, tile)
-        out_shape = (jax.ShapeDtypeStruct((rows, LANE), jnp.float32),) * 2
-    else:
-        ops = [theta2d, g2d] + sur_ops
-        specs = [tile, tile] + sur_specs
-        out_specs = tile
-        out_shape = jax.ShapeDtypeStruct((rows, LANE), jnp.float32)
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[seed_spec, scalar_spec] + specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(seed, scalars, *ops)
+    return _fused_call(theta2d, g2d, seed, scalars, variant=variant,
+                       dynamics=dynamics, r2d=r2d, mu_g=mu_g, mu_s=mu_s,
+                       lam_g=lam_g, lam_s=lam_s, leaf_blocks=(bpc,),
+                       block_rows=br, chains=chains, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -256,26 +236,25 @@ def fsgld_update_2d(theta2d: jax.Array, g2d: jax.Array, seed: jax.Array,
 #
 # The whole parameter pytree of a whole chain block rides in ONE
 # (C * rows_total, 128) buffer: each leaf owns a contiguous run of rows
-# padded up to a block multiple, chains are major. A static SEGMENT TABLE
-# (seg_leaf: block -> leaf id, seg_base: block -> element offset within the
-# leaf) rides in as scalar-prefetch operands; seed/scalar BlockSpec index
-# maps look the (chain, leaf) coordinate up in it, so one pallas_call per
-# step covers every leaf of every chain while noise streams stay
-# bit-identical to the per-leaf kernel above (same per-(chain, leaf) seed,
-# same in-leaf element index). ``dynamics='sghmc'`` adds a SECOND
-# chain-major buffer — the momenta — sharing the same segment table.
+# padded up to a block multiple, chains are major. The static per-leaf
+# block counts (``leaf_blocks``) let the kernel work out each block's
+# (chain, leaf) and in-leaf offset, so one pallas_call per step covers
+# every leaf of every chain while noise streams stay bit-identical to the
+# per-leaf kernel above (same per-(chain, leaf) seed, same in-leaf element
+# index). ``dynamics='sghmc'`` adds a SECOND chain-major buffer — the
+# momenta — over the same layout.
 # ---------------------------------------------------------------------------
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "variant", "dynamics", "interpret", "block_rows", "chains", "seg_leaf",
-    "seg_base"))
+    "variant", "dynamics", "interpret", "block_rows", "chains",
+    "leaf_blocks"))
 def fsgld_update_packed(theta2d: jax.Array, g2d: jax.Array,
                         seeds: jax.Array, scalars: jax.Array, *,
                         variant: str = "plain",
                         dynamics: str = "langevin", r2d=None,
                         mu_g=None, mu_s=None, lam_g=None, lam_s=None,
-                        seg_leaf: tuple = (0,), seg_base: tuple = (0,),
+                        leaf_blocks: tuple = (1,),
                         interpret: bool = False,
                         block_rows: int = PACK_BLOCK_ROWS,
                         chains: int = 1):
@@ -283,42 +262,54 @@ def fsgld_update_packed(theta2d: jax.Array, g2d: jax.Array,
 
     theta2d/g2d (and ``r2d``, the momenta, for ``dynamics='sghmc'``):
     (chains * rows_total, 128) chain-major packed buffers, rows_total =
-    block_rows * len(seg_leaf). seeds: (chains, L) uint32 — one stream per
-    (chain, leaf), matching the per-leaf kernel's seed derivation.
+    block_rows * sum(leaf_blocks). seeds: (chains, L) uint32 — one stream
+    per (chain, leaf), matching the per-leaf kernel's seed derivation.
     scalars: (chains, L, SCALAR_COLS) rows in the S_* layout (per-leaf
     scalar precisions for the 'scalar' variant live in S_LAMG/S_LAMS, the
     SGHMC friction in S_FRIC). mu_g/lam_g: (rows_total, 128) packed GLOBAL
     surrogate, re-read per chain; mu_s/lam_s: (chains * rows_total, 128)
     packed per-chain resident-client surrogates.
 
-    seg_leaf[j] names the leaf block j belongs to; seg_base[j] is the
-    element offset of block j inside that leaf's padded vector. Both are
-    STATIC tuples shipped as scalar-prefetch operands so the BlockSpec
-    index maps can route seed/scalar rows per (chain, leaf) — one grid,
-    one HBM pass, zero per-leaf dispatch. Bit-identical to per-leaf
-    ``fsgld_update_2d`` calls because pad rows at each leaf tail are
-    discarded at unpack and live elements keep their in-leaf index.
+    leaf_blocks[l] is the STATIC number of (block_rows, 128) blocks leaf l
+    owns in each chain's segment — one grid, one HBM pass, zero per-leaf
+    dispatch. Bit-identical to per-leaf ``fsgld_update_2d`` calls because
+    pad rows at each leaf tail are discarded at unpack and live elements
+    keep their in-leaf index.
     Returns theta' ('langevin') or the pair (theta', r') ('sghmc').
     """
+    assert seeds.shape == (chains, len(leaf_blocks)), \
+        (seeds.shape, chains, leaf_blocks)
+    return _fused_call(theta2d, g2d, seeds, scalars, variant=variant,
+                       dynamics=dynamics, r2d=r2d, mu_g=mu_g, mu_s=mu_s,
+                       lam_g=lam_g, lam_s=lam_s, leaf_blocks=leaf_blocks,
+                       block_rows=block_rows, chains=chains,
+                       interpret=interpret)
+
+
+def _fused_call(theta2d, g2d, seeds, scalars, *, variant, dynamics, r2d,
+                mu_g, mu_s, lam_g, lam_s, leaf_blocks, block_rows, chains,
+                interpret):
+    """The one pallas_call behind both launchers."""
     rows = theta2d.shape[0]
     assert theta2d.shape[1] == LANE, theta2d.shape
-    bpc = len(seg_leaf)
-    assert len(seg_base) == bpc, (len(seg_base), bpc)
+    bpc = sum(leaf_blocks)
     assert rows == chains * bpc * block_rows, (rows, chains, bpc, block_rows)
-    grid = (chains * bpc,)
-    seg_t = jnp.asarray(seg_leaf, jnp.int32)
-    base_t = jnp.asarray(seg_base, jnp.int32)
 
-    tile = pl.BlockSpec((block_rows, LANE), lambda i, sg, bs: (i, 0))
-    shared_tile = pl.BlockSpec((block_rows, LANE),
-                               lambda i, sg, bs: (i % bpc, 0))
-    seed_spec = pl.BlockSpec((1, 1),
-                             lambda i, sg, bs: (i // bpc, sg[i % bpc]))
-    scalar_spec = pl.BlockSpec((1, 1, SCALAR_COLS),
-                               lambda i, sg, bs: (i // bpc, sg[i % bpc], 0))
+    tile = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
+    shared_tile = pl.BlockSpec((block_rows, LANE), lambda i: (i % bpc, 0))
+    # per-(chain, leaf) seed and scalar rows ride SMEM as (1, 1, n) blocks
+    # of (C * L, 1, n) arrays: their last two dims equal the array's, which
+    # Mosaic accepts, where a (1, n) block over C rows is refused
+    def row(i):
+        chain, leaf, _ = _locate(i, leaf_blocks)
+        return chain * len(leaf_blocks) + leaf, 0, 0
 
-    kernel = _make_kernel(variant, dynamics, block_rows=block_rows, bpc=bpc,
-                          packed=True)
+    seed_spec = pl.BlockSpec((1, 1, 1), row, memory_space=pltpu.SMEM)
+    scalar_spec = pl.BlockSpec((1, 1, SCALAR_COLS), row,
+                               memory_space=pltpu.SMEM)
+
+    kernel = _make_kernel(variant, dynamics, block_rows=block_rows,
+                          leaf_blocks=leaf_blocks)
     sur_ops, sur_specs = _variant_ops(variant, mu_g, mu_s, lam_g, lam_s,
                                       tile, shared_tile)
     if dynamics == "sghmc":
@@ -333,15 +324,15 @@ def fsgld_update_packed(theta2d: jax.Array, g2d: jax.Array,
         out_specs = tile
         out_shape = jax.ShapeDtypeStruct((rows, LANE), jnp.float32)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[seed_spec, scalar_spec] + specs,
-        out_specs=out_specs,
-    )
+    # theta' (and r') overwrite theta's (r's) buffer: each block is read
+    # before it is written, and the state then needs no second copy in HBM
+    aliases = {2: 0, 3: 1} if dynamics == "sghmc" else {2: 0}
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid=(chains * bpc,),
+        in_specs=[seed_spec, scalar_spec] + specs,
+        out_specs=out_specs,
         out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
-    )(seg_t, base_t, seeds, scalars, *ops)
+    )(seeds.reshape(-1, 1, 1), scalars.reshape(-1, 1, SCALAR_COLS), *ops)

@@ -2,17 +2,17 @@
 
 `fused_update_tree` applies the kernel leaf-by-leaf over a parameter pytree:
 ravel -> pad to (rows, 128) -> pallas_call -> unpad/reshape, with a
-deterministic per-leaf seed folded out of a JAX PRNG key. On this CPU
-container the kernel runs in interpret mode (the TPU path is identical
-modulo `interpret=False`).
+deterministic per-leaf seed folded out of a JAX PRNG key. Off the TPU
+the kernel runs in interpret mode (the TPU path is identical modulo
+`interpret=False`).
 
 `PackedChains` is the single-launch layout (PR 2): every leaf of every
 chain lives in ONE chain-major (C * rows_total, 128) buffer, built once
 per run by `pack`; per-step updates go through `packed_step`, which issues
 exactly one `pallas_call` for the whole chain block using the layout's
-static segment table (see kernels/fsgld_update.py). The layout is
+static per-leaf block counts (see kernels/fsgld_update.py). The layout is
 MULTI-SEGMENT (PR 4): SGHMC dynamics add a second chain-major momentum
-buffer sharing the same segment table, and non-fp32 parameter leaves ride
+buffer over the same layout, and non-fp32 parameter leaves ride
 the fp32 buffer with a per-step `quantize` round-trip back to their
 storage dtype — bit-identical to the per-leaf kernel's dtype handling.
 """
@@ -30,9 +30,12 @@ from repro.kernels.fsgld_update import (LANE, PACK_BLOCK_ROWS, SCALAR_COLS,
 
 PyTree = Any
 
-# CPU container: interpret=True executes the kernel body in Python/XLA-CPU.
-# On a real TPU runtime set this to False (same kernel).
-INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret(flag: Optional[bool]) -> bool:
+    """``interpret=None`` resolves when the kernel is called, not when this
+    module is imported: compiled on a TPU backend, interpreted (the kernel
+    body run through XLA) on any other."""
+    return jax.default_backend() != "tpu" if flag is None else flag
 
 
 def _pad_2d(vec: jax.Array, block_rows: int):
@@ -67,7 +70,7 @@ def fused_update_flat(theta: jax.Array, g: jax.Array, seed: jax.Array, *,
     pair (theta', momentum'). Non-fp32 operands round-trip through fp32
     per step (the kernels compute at fp32 and cast back out).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     orig_shape, orig_dtype = theta.shape, theta.dtype
     th2, n = _pad_2d(theta.reshape(-1), block_rows)
     g2, _ = _pad_2d(g.reshape(-1), block_rows)
@@ -131,7 +134,7 @@ def fused_update_chains_flat(theta: jax.Array, g: jax.Array,
     ``dynamics='sghmc'`` carries the (C, ...) ``momentum`` stack through
     the SGHMC integrator and returns the (theta', momentum') pair.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     C = theta.shape[0]
     orig_shape, orig_dtype = theta.shape, theta.dtype
     per_block = block_rows * LANE
@@ -267,11 +270,11 @@ class PackedChains:
     Leaf l owns rows [row_offsets[l], row_offsets[l] + rows[l]) of every
     chain's segment; its first ``sizes[l]`` elements are live, the tail up
     to ``rows[l] * 128`` is pad (written by the kernel, never read back).
-    ``seg_leaf``/``seg_base`` are the per-block tables the packed kernel's
-    BlockSpec index maps consume: block j of a chain belongs to leaf
-    ``seg_leaf[j]`` and starts at in-leaf element ``seg_base[j]`` — that
-    base index is what keeps the in-kernel noise stream bit-identical to
-    the per-leaf kernel. Hashable (all-tuple) so it can key jit caches.
+    ``leaf_blocks`` (blocks per leaf in each chain's segment) is all the
+    packed kernel needs to work out each block's leaf and in-leaf element
+    offset — that offset is what keeps the in-kernel noise stream
+    bit-identical to the per-leaf kernel. Hashable (all-tuple) so it can
+    key jit caches.
     """
     treedef: Any
     shapes: tuple
@@ -281,17 +284,15 @@ class PackedChains:
     row_offsets: tuple    # first row of each leaf inside a chain segment
     rows_total: int
     block_rows: int
-    seg_leaf: tuple       # in-chain block -> leaf id
-    seg_base: tuple       # in-chain block -> element offset within leaf
 
     @property
     def num_leaves(self) -> int:
         return len(self.shapes)
 
     @property
-    def bpc(self) -> int:
-        """Blocks per chain (grid steps each chain contributes)."""
-        return len(self.seg_leaf)
+    def leaf_blocks(self) -> tuple:
+        """Blocks each leaf owns in a chain's segment."""
+        return tuple(r // self.block_rows for r in self.rows)
 
     def pack(self, tree: PyTree) -> jax.Array:
         """Leaves (C, *shape) -> (C * rows_total, 128) fp32, chain-major.
@@ -369,16 +370,10 @@ def make_packed_layout(theta: PyTree,
     for r in rows:
         row_offsets.append(acc)
         acc += r
-    seg_leaf, seg_base = [], []
-    for li, r in enumerate(rows):
-        for b in range(r // block_rows):
-            seg_leaf.append(li)
-            seg_base.append(b * per_block)
     return PackedChains(
         treedef=treedef, shapes=shapes, dtypes=dtypes, sizes=sizes,
         rows=rows, row_offsets=tuple(row_offsets), rows_total=acc,
-        block_rows=block_rows, seg_leaf=tuple(seg_leaf),
-        seg_base=tuple(seg_base))
+        block_rows=block_rows)
 
 
 def chain_leaf_seeds(keys: jax.Array, num_leaves: int) -> jax.Array:
@@ -427,13 +422,13 @@ def packed_step(layout: PackedChains, theta_p: jax.Array, g_p: jax.Array,
     ``chain_leaf_seeds``; scalars: (C, L, SCALAR_COLS) from
     ``packed_scalar_rows``. Returns theta_p' or (theta_p', r_p').
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = _interpret(interpret)
     C = seeds.shape[0]
     return fsgld_update_packed(
         theta_p, g_p, seeds, scalars, variant=variant, dynamics=dynamics,
         r2d=r_p, mu_g=mu_g, mu_s=mu_s, lam_g=lam_g, lam_s=lam_s,
-        seg_leaf=layout.seg_leaf, seg_base=layout.seg_base,
-        block_rows=layout.block_rows, chains=C, interpret=interpret)
+        leaf_blocks=layout.leaf_blocks, block_rows=layout.block_rows,
+        chains=C, interpret=interpret)
 
 
 def fused_update_tree(theta: PyTree, g: PyTree, key: jax.Array, *, h, scale,

@@ -70,7 +70,7 @@ def lower_one(arch: str, shape_name: str, mesh, sampler: SamplerConfig):
             args.append(ins["enc_out"])
             in_sh.append(_shardings(batch_specs(
                 {"e": ins["enc_out"]}, mesh), mesh)["e"])
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 serve, in_shardings=tuple(in_sh),
                 out_shardings=(tok_shard["pos"], cache_shard),
@@ -86,7 +86,7 @@ def lower_one(arch: str, shape_name: str, mesh, sampler: SamplerConfig):
         out_shard = _shardings(batch_specs(
             {"t": jax.ShapeDtypeStruct((shape.global_batch,), jnp.int32)},
             mesh), mesh)["t"]
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 prefill, in_shardings=(pshard, bshard),
                 out_shardings=out_shard,
@@ -108,7 +108,7 @@ def lower_one(arch: str, shape_name: str, mesh, sampler: SamplerConfig):
             return step(params, surr, batch,
                         jax.random.wrap_key_data(key_data))
 
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step_key,
                 in_shardings=(pshard, surr_shard, bshard,
@@ -116,7 +116,7 @@ def lower_one(arch: str, shape_name: str, mesh, sampler: SamplerConfig):
                 out_shardings=(pshard, NamedSharding(mesh, P())),
             ).lower(pshape, surr, batch, key)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = lowered.compile()
     return lowered, compiled
 
@@ -149,8 +149,6 @@ def collective_bytes_from_text(txt: str) -> dict:
 def analyze(lowered, compiled) -> dict:
     from repro.roofline.hlo_analysis import analyze_text
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     txt = compiled.as_text()
     coll = collective_bytes_from_text(txt)

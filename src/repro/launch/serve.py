@@ -27,6 +27,7 @@ import argparse
 import warnings
 
 from repro.api import FSGLD, Serving
+from repro.launch.cache import enable_compile_cache
 from repro.obs import trace as obs_trace
 
 _ckpt_warned = False
@@ -54,6 +55,7 @@ def main(argv=None):
                     help="also append structured trace events/spans "
                          "(refreshes, prefill/decode) to this JSONL file")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     obs_trace.configure(args.log_jsonl, echo=True)
     try:
         return _serve(args)

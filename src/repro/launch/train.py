@@ -13,8 +13,11 @@ Phases (paper Algorithm 1 + Sec 3.1):
      ppermute federated round are retired — both scales share one
      reassignment/collective path.
 
-On this CPU container run with ``--smoke`` (reduced config, 1x1 mesh); on a
-real cluster the same script drives the 16x16 / 2x16x16 production meshes.
+The mesh is built from the devices present: ``data`` = device count,
+``model`` = 1, so chains spread over every local chip. ``--smoke`` picks
+the toy configuration of the family (the CPU tests); without it the
+published configuration runs at its published widths, and ``--layers N``
+cuts its depth to fit one chip (printed as a cut).
 
 KNOWN LIMIT (ROADMAP open item): the chain engine places chains on the
 mesh 'data' axis and keeps parameters REPLICATED over 'model' (that axis
@@ -26,10 +29,13 @@ data-axis shard_map.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b --smoke \
         --rounds 10 --method fsgld
+    python -m repro.launch.train --arch h2o-danube-1.8b --layers 2 \
+        --use-kernel --chains 1 --seq 1024 --batch 4   # on a TPU chip
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
 
@@ -40,7 +46,8 @@ import numpy as np
 from repro import api, checkpoint
 from repro.configs import get_config, get_smoke_config
 from repro.data import token_shards
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_sim_mesh
 from repro.models import init_params, log_lik_fn
 from repro.obs import trace as obs_trace
 from repro.obs import write_metrics_jsonl, write_prometheus
@@ -86,10 +93,24 @@ def _sample_into_bank(fsgld, key, params, cfg, args, federation):
 
 
 def main(argv=None):
+    run(argv)
+    return 0
+
+
+def run(argv=None, *, devices=None) -> dict:
+    """Parse ``argv`` and run the job on a (len(devices), 1) mesh (default:
+    every local device). Returns what an in-process caller checks: ``cfg``
+    (the configuration run, cut included), ``params`` (the initial state),
+    ``finals`` (the stacked (C, ...) final chain parameters, on device),
+    ``probe`` (the batch ll/token is measured on) and ``ll_per_token``
+    (per chain, on that batch)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config + 1x1 mesh (CPU container)")
+                    help="the family's toy config (CPU tests)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth cut: replace num_layers of the config "
+                         "and nothing else (every width stays)")
     ap.add_argument("--method", default="fsgld",
                     choices=["sgld", "dsgld", "fsgld", "fald"])
     ap.add_argument("--rounds", type=int, default=5)
@@ -148,7 +169,6 @@ def main(argv=None):
     ap.add_argument("--shard-size", type=int, default=64)
     ap.add_argument("--step-size", type=float, default=1e-5)
     ap.add_argument("--fit-steps", type=int, default=20)
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--draw-bank", default=None,
                     help="versioned draw-bank DIRECTORY: sampling runs in "
@@ -231,6 +251,7 @@ def main(argv=None):
             "--method dsgld or fald, or pass a prefit bank through the "
             "api facade")
 
+    enable_compile_cache()
     telemetry = api.Telemetry(log_every=args.log_every) if obs else None
     if args.metrics_dir is not None:
         os.makedirs(args.metrics_dir, exist_ok=True)
@@ -240,22 +261,26 @@ def main(argv=None):
     elif args.log_every is not None:
         obs_trace.configure(echo=True)
     try:
-        return _train(args, telemetry)
+        return _train(args, telemetry, devices or jax.devices())
     finally:
         obs_trace.configure()  # don't leak the tracer to callers
 
 
-def _train(args, telemetry):
+def _train(args, telemetry, devices):
     obs = telemetry is not None
     n_clients = args.clients if args.clients is not None else args.num_shards
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    mesh = make_host_mesh() if args.smoke \
-        else make_production_mesh(multi_pod=args.multi_pod)
+    if args.layers is not None:
+        print(f"cut: num_layers {cfg.num_layers} -> {args.layers} "
+              f"(every width as configured)")
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    mesh = make_sim_mesh(data=len(devices), model=1, devices=devices)
     key = jax.random.PRNGKey(args.seed)
     k_param, k_data, k_fit, k_run = jax.random.split(key, 4)
 
     print(f"arch={cfg.name} method={args.method} shards={n_clients} "
-          f"mesh={dict(mesh.shape)}"
+          f"mesh={dict(mesh.shape)} "
+          f"device={devices[0].platform}"
           + (f" resident={args.resident}" if args.resident else ""))
     params = init_params(cfg, k_param)
     n_params = sum(p.size for p in jax.tree.leaves(params))
@@ -317,8 +342,9 @@ def _train(args, telemetry):
     # ---- phase 1: surrogates (once, before sampling) ----
     if args.method == "fsgld":
         t0 = time.time()
-        fsgld.fit(k_fit, params)
+        jax.block_until_ready(fsgld.fit(k_fit, params))
         print(f"surrogates fitted in {time.time()-t0:.1f}s "
+              f"(compilation included) "
               f"(communicated once; means stored as "
               f"{cfg.surrogate_dtype})")
 
@@ -344,18 +370,20 @@ def _train(args, telemetry):
             print(f"metrics -> {mj} + {mp} "
                   f"({frame.rounds} rounds x {frame.n_chains} chains x "
                   f"{len(frame.names)} metrics)")
+    jax.block_until_ready(finals)
     dt = time.time() - t0
     probe_rows = (shards.rows(np.arange(1)) if args.clients is not None
                   else shards)
     probe = jax.tree.map(lambda d: d[0][:args.batch], probe_rows)
-    lls = jax.vmap(lambda p: log_lik_fn(p, cfg, probe))(finals)
+    lls = jax.jit(jax.vmap(lambda p, b: log_lik_fn(p, cfg, b),
+                           in_axes=(0, None)))(finals, probe)
     lls = np.asarray(lls) / probe["tokens"].size
     for c, ll in enumerate(lls):
         print(f"chain {c:3d} ll/token={float(ll):8.4f}")
     steps = args.rounds * args.local_updates * args.chains
     print(f"{args.chains} chain(s) x {args.rounds} rounds "
           f"({steps} chain-steps) in {dt:.1f}s "
-          f"= {steps / dt:.1f} steps/s "
+          f"= {steps / dt:.1f} steps/s, compilation included "
           f"[reassign={reassign} executor={executor}"
           f"{' federation=' + args.federation if args.federation else ''}]")
     if args.ckpt:
@@ -366,7 +394,8 @@ def _train(args, telemetry):
                                "chains": args.chains})
         print(f"checkpoint -> {args.ckpt}")
     print(f"final ll/token {float(np.mean(lls)):.4f}")
-    return 0
+    return {"cfg": cfg, "params": params, "finals": finals,
+            "probe": probe, "ll_per_token": lls}
 
 
 if __name__ == "__main__":

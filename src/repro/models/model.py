@@ -27,29 +27,20 @@ from repro.models import layers as L
 ACT_DTYPE = jnp.bfloat16
 
 
-def _ambient_mesh():
-    """The legacy `with mesh:` context mesh, if any (dry-run / production
-    path). Returns None on the bare CPU test path."""
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001
-        return None
-    return None
-
-
 def _shard_batch(x):
     """Anchor activation sharding: batch over (pod?, data), rest replicated.
     Without this anchor GSPMD drops batch sharding at the remat+scan
     boundary and silently replicates whole-layer compute on every device
-    (16-64x redundant flops — caught by the roofline analyzer)."""
-    mesh = _ambient_mesh()
-    if mesh is None:
-        return x
-    from jax.sharding import PartitionSpec as P
-    baxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    (16-64x redundant flops — caught by the roofline analyzer).
+
+    Acts only under a ``jax.set_mesh`` context (the dry-run) and only on
+    its ``Auto`` axes: inside the chain engine's ``shard_map`` the axes are
+    ``Manual`` and each device already holds its own chain block."""
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == AxisType.Auto}
+    baxes = tuple(a for a in ("pod", "data") if a in auto)
     if not baxes or x.shape[0] % \
             int(np.prod([mesh.shape[a] for a in baxes])) != 0:
         return x

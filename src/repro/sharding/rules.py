@@ -204,7 +204,7 @@ def packed_chain_spec() -> P:
     unpacked (C, ...) tree gets from ``chain_spec`` (requires
     C % |data| == 0, which the engine already enforces). EVERY
     chain-major segment buffer of the multi-segment state shares this
-    spec — the SGHMC momentum buffer rides the same segment table and
+    spec — the SGHMC momentum buffer rides the same packed layout and
     the same chain-major row order as the parameter buffer."""
     return P(CHAIN_AXIS, None)
 

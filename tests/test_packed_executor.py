@@ -27,7 +27,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs.base import SamplerConfig
 from repro.core import (FederatedSampler, MeshChainEngine, make_bank,
                         pad_shards, analytic_gaussian_likelihood_surrogate)
-from repro.core.engine import pack_bank
+from repro.core.engine import pack_bank, resident_surrogates
 from repro.kernels import ops
 
 
@@ -90,8 +90,9 @@ def test_packed_step_bitmatches_per_leaf_kernel_multileaf(variant,
     key = jax.random.PRNGKey(0)
     C, S = 4, 5
     # "b" spans MULTIPLE packed blocks (2*1300 > 2 * block_rows*LANE =
-    # 2048): seg_base > 0 and repeated seg_leaf entries — the segment
-    # paths a single-block leaf never touches — are exercised here
+    # 2048): a non-zero in-leaf block offset and a leaf spanning several
+    # blocks — the paths a single-block leaf never touches — are
+    # exercised here
     shapes = {"a": (37,), "b": (2, 1300), "c": (3,)}
     ks = jax.random.split(key, 10)
     theta = {n: jax.random.normal(jax.random.fold_in(ks[0], i), (C,) + s)
@@ -136,7 +137,7 @@ def test_packed_step_bitmatches_per_leaf_kernel_multileaf(variant,
     else:
         pb = pack_bank(layout, bank)
         mu_g = pb["mu_g"]
-        mu_s = pb["means"][sids].reshape(-1, ops.LANE)
+        mu_s, _ = resident_surrogates(layout, pb, sids)
         lam_g_leaf = pb["lam_g_leaf"]
         lam_s_leaf = pb["lam_s_leaf"][sids]
     scalars = ops.packed_scalar_rows(
@@ -181,13 +182,13 @@ def test_packed_step_bitmatches_per_leaf_kernel_diag():
 
     layout = ops.make_packed_layout(theta[0])
     pb = pack_bank(layout, bank)
+    mu_s, lam_s = resident_surrogates(layout, pb, sids)
     seeds = ops.chain_leaf_seeds(keys, layout.num_leaves)
     scalars = ops.packed_scalar_rows(layout, scale=scale, f_s=f_s, **kw)
     out_p = ops.packed_step(
         layout, layout.pack(theta), layout.pack(g), seeds, scalars,
-        variant="diag", mu_g=pb["mu_g"], lam_g=pb["lam_g"],
-        mu_s=pb["means"][sids].reshape(-1, ops.LANE),
-        lam_s=pb["precs"][sids].reshape(-1, ops.LANE))
+        variant="diag", mu_g=pb["mu_g"], lam_g=pb["lam_g"], mu_s=mu_s,
+        lam_s=lam_s)
     np.testing.assert_array_equal(np.asarray(layout.unpack(out_p)),
                                   np.asarray(ref))
 
@@ -444,7 +445,6 @@ def test_masked_grad_vmap_skips_pad_chain_gradients():
     full block and discard. Asserted structurally on the branch jaxprs."""
     from repro.core.engine import make_masked_grad_vmap
     from repro.launch.mesh import make_host_mesh
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     d = 3
@@ -460,8 +460,8 @@ def test_masked_grad_vmap_skips_pad_chain_gradients():
     # axis_index needs an axis context: trace inside shard_map on the
     # host mesh (the switch itself only cares about the traced index)
     mesh = make_host_mesh()
-    fn = shard_map(masked, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(masked, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=P(), check_vma=False)
     jaxpr = jax.make_jaxpr(fn)(thetas, batches)
     conds = [e for e in _all_eqns(jaxpr.jaxpr)
              if e.primitive.name == "cond"]

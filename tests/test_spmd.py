@@ -31,8 +31,9 @@ import numpy as np
 from repro import api
 from repro.configs import get_smoke_config
 from repro.data import token_shards
+from repro.launch.mesh import make_sim_mesh
 from repro.models import init_params, log_lik_fn
-mesh = jax.make_mesh((4, 1), ("data", "model"))
+mesh = make_sim_mesh(data=4, model=1)
 cfg = get_smoke_config("qwen3-1.7b")
 params = init_params(cfg, jax.random.PRNGKey(0))
 shards = token_shards(jax.random.PRNGKey(1), num_shards=4, shard_size=16,

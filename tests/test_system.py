@@ -161,8 +161,5 @@ def test_hlo_analyzer_matches_xla_on_loop_free():
           for s in [(64, 128), (128, 32), (16, 64)]]
     c = jax.jit(g).lower(*xs).compile()
     got = analyze_text(c.as_text())["flops"]
-    cost = c.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x: one dict per program
-        cost = cost[0]
-    want = cost["flops"]
+    want = c.cost_analysis()["flops"]
     assert abs(got - want) / want < 0.05, (got, want)
