@@ -92,6 +92,7 @@ pure-JAX FA-LD oracle lives in ``repro.rivals.fald``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Optional
 
@@ -308,15 +309,18 @@ def make_chain_round_fn(log_lik_fn: LogLikFn, cfg: SamplerConfig,
             thetas, r = carry if hmc else (carry, None)
             kk = jax.vmap(jax.random.split)(ks)       # (C, 2, 2)
             k_batch, k_step = kk[:, 0], kk[:, 1]
-            batches = jax.vmap(
-                lambda k, s: sample(k, s, shard_data, sizes_rt))(
-                k_batch, sids)
-            glls = grad_vmap(thetas, batches)
-            out = kops.fused_update_chains_tree(
-                thetas, glls, k_step, h=cfg.step_size, scale=scale,
-                f_s=f_s, prior_prec=cfg.prior_precision, alpha=cfg.alpha,
-                bank=bank, sids=sids, surrogate_kind=bank_kind,
-                momentum=r, **dyn_kw)
+            with jax.named_scope("fsgld.batch"):
+                batches = jax.vmap(
+                    lambda k, s: sample(k, s, shard_data, sizes_rt))(
+                    k_batch, sids)
+            with jax.named_scope("fsgld.grad"):
+                glls = grad_vmap(thetas, batches)
+            with jax.named_scope("fsgld.update"):
+                out = kops.fused_update_chains_tree(
+                    thetas, glls, k_step, h=cfg.step_size, scale=scale,
+                    f_s=f_s, prior_prec=cfg.prior_precision,
+                    alpha=cfg.alpha, bank=bank, sids=sids,
+                    surrogate_kind=bank_kind, momentum=r, **dyn_kw)
             thetas = out[0] if hmc else out
             carry = out if hmc else thetas
             return carry, thetas if collect else None
@@ -436,26 +440,28 @@ def make_packed_round_fn(log_lik_fn: LogLikFn, cfg: SamplerConfig,
         sizes_rt = None if sp_rt is None else sp_rt[0]
         mu_g = mu_s = lam_gp = lam_sp = None
         lam_g_leaf = lam_s_leaf = None
-        if bank_kind is None:
-            variant = "plain"
-        elif bank_kind == "diag":
-            variant = "diag"
-            mu_g, lam_gp = pbank["mu_g"], pbank["lam_g"]
-            mu_s, lam_sp = resident_surrogates(layout, pbank, sids)
-        elif bank_kind == "scalar":
-            variant = "scalar"
-            mu_g = pbank["mu_g"]
-            mu_s, _ = resident_surrogates(layout, pbank, sids)
-            lam_g_leaf = pbank["lam_g_leaf"]
-            lam_s_leaf = pbank["lam_s_leaf"][sids]
-        else:
-            raise ValueError(bank_kind)
-        scalars = kops.packed_scalar_rows(
-            layout, h=cfg.step_size, scale=scale, f_s=f_s,
-            prior_prec=cfg.prior_precision, alpha=cfg.alpha,
-            temperature=(sghmc.temperature if hmc else cfg.temperature),
-            lam_g_leaf=lam_g_leaf, lam_s_leaf=lam_s_leaf,
-            friction=(sghmc.friction if hmc else 0.0))
+        with jax.named_scope("fsgld.conducive"):
+            if bank_kind is None:
+                variant = "plain"
+            elif bank_kind == "diag":
+                variant = "diag"
+                mu_g, lam_gp = pbank["mu_g"], pbank["lam_g"]
+                mu_s, lam_sp = resident_surrogates(layout, pbank, sids)
+            elif bank_kind == "scalar":
+                variant = "scalar"
+                mu_g = pbank["mu_g"]
+                mu_s, _ = resident_surrogates(layout, pbank, sids)
+                lam_g_leaf = pbank["lam_g_leaf"]
+                lam_s_leaf = pbank["lam_s_leaf"][sids]
+            else:
+                raise ValueError(bank_kind)
+            scalars = kops.packed_scalar_rows(
+                layout, h=cfg.step_size, scale=scale, f_s=f_s,
+                prior_prec=cfg.prior_precision, alpha=cfg.alpha,
+                temperature=(sghmc.temperature if hmc
+                             else cfg.temperature),
+                lam_g_leaf=lam_g_leaf, lam_s_leaf=lam_s_leaf,
+                friction=(sghmc.friction if hmc else 0.0))
 
         def body(carry, ks):
             if hmc:
@@ -464,22 +470,27 @@ def make_packed_round_fn(log_lik_fn: LogLikFn, cfg: SamplerConfig,
                 (th_p, thetas), r_p = carry, None
             kk = jax.vmap(jax.random.split)(ks)       # (C, 2, 2)
             k_batch, k_step = kk[:, 0], kk[:, 1]
-            batches = jax.vmap(
-                lambda k, s: sample(k, s, shard_data, sizes_rt))(
-                k_batch, sids)
-            glls = grad_vmap(thetas, batches)
-            g_p = layout.pack(glls)
-            seeds = kops.chain_leaf_seeds(k_step, L)
-            out = kops.packed_step(
-                layout, th_p, g_p, seeds, scalars, variant=variant,
-                mu_g=mu_g, mu_s=mu_s, lam_g=lam_gp, lam_s=lam_sp,
-                r_p=r_p, dynamics=dynamics)
-            th_p = layout.quantize(out[0] if hmc else out)
-            thetas = layout.unpack(th_p)
-            if hmc:
-                carry = (th_p, layout.quantize(out[1]), thetas)
-            else:
-                carry = (th_p, thetas)
+            with jax.named_scope("fsgld.batch"):
+                batches = jax.vmap(
+                    lambda k, s: sample(k, s, shard_data, sizes_rt))(
+                    k_batch, sids)
+            with jax.named_scope("fsgld.grad"):
+                glls = grad_vmap(thetas, batches)
+            with jax.named_scope("fsgld.pack"):
+                g_p = layout.pack(glls)
+            with jax.named_scope("fsgld.update"):
+                seeds = kops.chain_leaf_seeds(k_step, L)
+                out = kops.packed_step(
+                    layout, th_p, g_p, seeds, scalars, variant=variant,
+                    mu_g=mu_g, mu_s=mu_s, lam_g=lam_gp, lam_s=lam_sp,
+                    r_p=r_p, dynamics=dynamics)
+            with jax.named_scope("fsgld.pack"):
+                th_p = layout.quantize(out[0] if hmc else out)
+                thetas = layout.unpack(th_p)
+                if hmc:
+                    carry = (th_p, layout.quantize(out[1]), thetas)
+                else:
+                    carry = (th_p, thetas)
             return carry, thetas if collect else None
 
         keys_t = jax.vmap(lambda k: jax.random.split(
@@ -496,6 +507,19 @@ def make_packed_round_fn(log_lik_fn: LogLikFn, cfg: SamplerConfig,
 # ---------------------------------------------------------------------------
 # the engine
 # ---------------------------------------------------------------------------
+
+def _traced_run(run):
+    """``run`` inside an ``engine.run`` span whose ``executor_built``
+    counts the executors this call built (their cache misses)."""
+    @functools.wraps(run)
+    def traced(self, key, theta0, num_rounds, **opts):
+        with obs_trace.span("engine.run", rounds=int(num_rounds)) as span:
+            built = len(self._executors)
+            out = run(self, key, theta0, num_rounds, **opts)
+            span.set(executor_built=len(self._executors) - built)
+        return out
+    return traced
+
 
 @dataclasses.dataclass
 class MeshChainEngine:
@@ -798,11 +822,13 @@ class MeshChainEngine:
         # (repacking the packed buffers — lossless: the pallas update is
         # elementwise, so buffer pad lanes never feed real lanes).
         if layout is not None:
+            @jax.named_scope("fsgld.pack")
             def get_view(st):
                 if hmc:
                     return st[2], layout.unpack(st[1])
                 return st[1], None
 
+            @jax.named_scope("fsgld.pack")
             def set_view(st, th, r):
                 if hmc:
                     return (layout.pack(th), layout.pack(r), th)
@@ -887,16 +913,20 @@ class MeshChainEngine:
             else:
                 to_local = lambda s: s  # noqa: E731
             if layout is not None:
-                rt_bank = pack_bank(
-                    layout, bank_rt if cfg.method == "fsgld" else None)
-                if hmc:
-                    th_c, r_c = chains
-                    # the momenta ride a SECOND chain-major buffer over
-                    # the SAME packed layout (their own seed stream is
-                    # the per-step noise draw routed by seed BlockSpecs)
-                    state = (layout.pack(th_c), layout.pack(r_c), th_c)
-                else:
-                    state = (layout.pack(chains), chains)
+                with jax.named_scope("fsgld.conducive"):
+                    rt_bank = pack_bank(
+                        layout, bank_rt if cfg.method == "fsgld" else None)
+                with jax.named_scope("fsgld.pack"):
+                    if hmc:
+                        th_c, r_c = chains
+                        # the momenta ride a SECOND chain-major buffer
+                        # over the SAME packed layout (their own seed
+                        # stream is the per-step noise draw routed by
+                        # seed BlockSpecs)
+                        state = (layout.pack(th_c), layout.pack(r_c),
+                                 th_c)
+                    else:
+                        state = (layout.pack(chains), chains)
             else:
                 rt_bank = bank_rt
                 state = chains
@@ -1328,8 +1358,9 @@ class MeshChainEngine:
                 tmet = {k: jnp.swapaxes(v, 0, 1)
                         for k, v in tmet.items()}
             if layout is not None:
-                chains_out = ((state[2], layout.unpack(state[1])) if hmc
-                              else state[1])
+                with jax.named_scope("fsgld.pack"):
+                    chains_out = ((state[2], layout.unpack(state[1]))
+                                  if hmc else state[1])
             else:
                 chains_out = state
             if collect:
@@ -1386,6 +1417,7 @@ class MeshChainEngine:
 
     # -- server-side loop --------------------------------------------------
 
+    @_traced_run
     def run(self, key: jax.Array, theta0: PyTree, num_rounds: int, *,
             n_chains: int = 1, reassign: str = "categorical",
             collect_every: int = 1, refresh_every: Optional[int] = None,
@@ -1447,6 +1479,13 @@ class MeshChainEngine:
         at its resume point). Telemetry-off runs are bitwise identical
         to telemetry-on runs — and to runs on code that predates the
         telemetry layer.
+
+        Host spans (``repro.obs.trace``): ``engine.run`` around the call
+        (``executor_built``: executors built, i.e. cache misses), and in
+        it ``engine.layout`` (resolving the packed layout),
+        ``engine.stage`` (copying and placing the chain state, setting
+        up the carries; ``bytes``: the chain state staged) and one
+        ``engine.segment`` per executor dispatch.
         """
         d_size = self.mesh.shape["data"]
         n_total = n_chains + (-n_chains) % d_size
@@ -1528,59 +1567,64 @@ class MeshChainEngine:
         # the packed layout is built from the PARAMETER pytree alone: the
         # sghmc momenta share its structure (and hence its packed layout)
         ex_theta = theta0[0] if self.dynamics == "sghmc" else theta0
-        layout = self._layout_for(
-            jax.tree.map(lambda t: t[0], ex_theta) if stacked else ex_theta)
-        cshard = NamedSharding(self.mesh, self._chain_spec())
-        if stacked:
-            assert jax.tree.leaves(theta0)[0].shape[0] == n_chains, \
-                (jax.tree.leaves(theta0)[0].shape, n_chains)
-            # pad chains replicate chain 0's state (their updates are
-            # computed and discarded — any finite state works). The
-            # unpadded leaves are COPIED: the executor donates its chain
-            # operand, and donating the caller's own arrays would delete
-            # them under a round-at-a-time driver.
-            chains = jax.tree.map(
-                lambda t: jnp.concatenate(
-                    [t, jnp.broadcast_to(t[:1], (n_total - n_chains,)
-                                         + t.shape[1:])])
-                if n_total > n_chains else t.copy(), theta0)
-        else:
-            chains = jax.tree.map(
-                lambda t: jnp.broadcast_to(
-                    t[None], (n_total,) + t.shape).copy(), theta0)
-        chains = jax.device_put(
-            chains, jax.tree.map(lambda _: cshard, chains))
-        bank_rt = self.bank
-        take = (lambda t: t[:n_chains]) if n_total > n_chains \
-            else (lambda t: t)
+        with obs_trace.span("engine.layout"):
+            layout = self._layout_for(
+                jax.tree.map(lambda t: t[0], ex_theta) if stacked
+                else ex_theta)
+        with obs_trace.span("engine.stage") as stage:
+            cshard = NamedSharding(self.mesh, self._chain_spec())
+            if stacked:
+                assert jax.tree.leaves(theta0)[0].shape[0] == n_chains, \
+                    (jax.tree.leaves(theta0)[0].shape, n_chains)
+                # pad chains replicate chain 0's state (their updates are
+                # computed and discarded — any finite state works). The
+                # unpadded leaves are COPIED: the executor donates its chain
+                # operand, and donating the caller's own arrays would delete
+                # them under a round-at-a-time driver.
+                chains = jax.tree.map(
+                    lambda t: jnp.concatenate(
+                        [t, jnp.broadcast_to(t[:1], (n_total - n_chains,)
+                                             + t.shape[1:])])
+                    if n_total > n_chains else t.copy(), theta0)
+            else:
+                chains = jax.tree.map(
+                    lambda t: jnp.broadcast_to(
+                        t[None], (n_total,) + t.shape).copy(), theta0)
+            chains = jax.device_put(
+                chains, jax.tree.map(lambda _: cshard, chains))
+            stage.set(bytes=sum(int(t.nbytes)
+                                for t in jax.tree.leaves(chains)))
+            bank_rt = self.bank
+            take = (lambda t: t[:n_chains]) if n_total > n_chains \
+                else (lambda t: t)
 
-        # in-scan carries threaded through the executor I/O (so segment
-        # boundaries — snapshots, resume — never reset them)
-        hw = None
-        if recovery is not None:
-            # the divergence probe window rides the carry as a (C, W)
-            # ring, -inf padded (= empty)
-            hw = (jnp.zeros((n_total,), jnp.int32),
-                  jnp.full((n_total, recovery.window), -jnp.inf,
-                           jnp.float32))
-        fedc = None
-        # FA-LD routes through the federated round body even with no
-        # Federation spec (see _executor) — it needs the fed carry
-        use_fed = fed is not None or self.aggregation == "fald"
-        if use_fed:
-            comp0 = fed.compression if fed is not None else None
-            cst0 = None
-            if comp0 is not None and not comp0.identity:
-                from repro.fed.compress import make_flattener
-                th_part = chains[0] if self.dynamics == "sghmc" else chains
-                flatten, _, _ = make_flattener(th_part)
-                # copy: flatten() can alias the (donated) chains buffer
-                ref0 = jnp.array(flatten(th_part), copy=True)
-                cst0 = (ref0, jnp.zeros_like(ref0))
-                if comp0.use_dual:
-                    # dual-leg error feedback rides a third carry slot
-                    cst0 = cst0 + (jnp.zeros_like(ref0),)
-            fedc = (jnp.zeros((n_total,), jnp.int32), cst0)
+            # in-scan carries threaded through the executor I/O (so segment
+            # boundaries — snapshots, resume — never reset them)
+            hw = None
+            if recovery is not None:
+                # the divergence probe window rides the carry as a (C, W)
+                # ring, -inf padded (= empty)
+                hw = (jnp.zeros((n_total,), jnp.int32),
+                      jnp.full((n_total, recovery.window), -jnp.inf,
+                               jnp.float32))
+            fedc = None
+            # FA-LD routes through the federated round body even with no
+            # Federation spec (see _executor) — it needs the fed carry
+            use_fed = fed is not None or self.aggregation == "fald"
+            if use_fed:
+                comp0 = fed.compression if fed is not None else None
+                cst0 = None
+                if comp0 is not None and not comp0.identity:
+                    from repro.fed.compress import make_flattener
+                    th_part = chains[0] if self.dynamics == "sghmc" else chains
+                    flatten, _, _ = make_flattener(th_part)
+                    # copy: flatten() can alias the (donated) chains buffer
+                    ref0 = jnp.array(flatten(th_part), copy=True)
+                    cst0 = (ref0, jnp.zeros_like(ref0))
+                    if comp0.use_dual:
+                        # dual-leg error feedback rides a third carry slot
+                        cst0 = cst0 + (jnp.zeros_like(ref0),)
+                fedc = (jnp.zeros((n_total,), jnp.int32), cst0)
 
         if stream is not None:
             return self._run_streamed(

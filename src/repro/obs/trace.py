@@ -1,11 +1,12 @@
-"""Host-side tracing: monotonic-clock spans + structured events, JSONL.
+"""Host-side tracing: monotonic-clock spans + structured events.
 
 The sampler's device work is one opaque scan dispatch; everything the
-HOST does around it — staging streamed windows, snapshot I/O, draw-bank
-refreshes, serving prefill/decode — is what this module makes visible.
-One module-level tracer (disabled by default: every call is a no-op on a
-shared null object, so instrumented code paths cost nothing when nobody
-is watching), configured once per process by the CLI entry points::
+HOST does around it — the engine's per-call layout and staging, streamed
+windows, snapshot I/O, draw-bank refreshes, serving prefill/decode — is
+what this module makes visible. One module-level tracer (disabled by
+default: every call is a no-op on a shared null object, so instrumented
+code paths cost nothing when nobody is watching), configured once per
+process by the entry points::
 
     from repro.obs import trace
     trace.configure(path="run/trace.jsonl", echo=True)
@@ -13,15 +14,24 @@ is watching), configured once per process by the CLI entry points::
         ...
     trace.event("engine.progress", round=8, steps_per_s=1.2e5)
 
-Span lines carry the WALL-clock start (``ts``, epoch seconds — for
-cross-process alignment) and a MONOTONIC duration (``dur_s`` — immune to
-clock steps), plus the nesting ``depth`` and ``parent`` span name from a
-thread-local stack, so a reader can rebuild the span tree from the flat
-JSONL. ``echo=True`` additionally prints one compact human line per
-event — the structured replacement for the bare ``print``/``warnings``
-progress messages the CLIs used to emit. ``profiler=True`` wraps every
-span in a ``jax.profiler.TraceAnnotation`` so host spans line up with
-device traces in the profiler UI.
+Three sinks, any combination:
+
+* ``path``: span and event records kept in memory and written as JSONL
+  at ``close()`` (``configure()`` closes the tracer it replaces). Span
+  records carry the WALL-clock start (``ts``, epoch seconds — for
+  cross-process alignment) and a MONOTONIC duration (``dur_s`` — immune
+  to clock steps), plus the nesting ``depth`` and ``parent`` span name
+  from a thread-local stack, so a reader can rebuild the span tree from
+  the flat JSONL.
+* ``echo``: one compact human line per record, printed at once — the
+  structured replacement for bare ``print`` progress messages.
+* ``profiler``: every span enters a ``jax.profiler.TraceAnnotation``
+  carrying its attributes, so host spans land in a profiler trace
+  (``jax.profiler.start_trace``) on the device's clock, attributes as
+  event stats. Alone it builds no record.
+
+``span.set(k=v)`` adds attributes known only inside the span (counters);
+they reach the record and the annotation alike.
 """
 from __future__ import annotations
 
@@ -41,6 +51,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -56,52 +69,67 @@ class _Span:
         self._prof = None
 
     def __enter__(self):
-        tls = self.tracer._tls
-        stack = getattr(tls, "stack", None)
-        if stack is None:
-            stack = tls.stack = []
-        self.depth = len(stack)
-        self.parent = stack[-1] if stack else None
-        stack.append(self.name)
-        self.ts = time.time()
-        self.t0 = time.monotonic()
-        if self.tracer.profiler:
-            try:
-                import jax
-                self._prof = jax.profiler.TraceAnnotation(self.name)
-                self._prof.__enter__()
-            except Exception:  # noqa: BLE001 - annotations are best-effort
-                self._prof = None
+        tr = self.tracer
+        if tr.recording:
+            stack = getattr(tr._tls, "stack", None)
+            if stack is None:
+                stack = tr._tls.stack = []
+            self.depth = len(stack)
+            self.parent = stack[-1] if stack else None
+            stack.append(self.name)
+            self.ts = time.time()
+            self.t0 = time.monotonic()
+        if tr._annotation is not None:
+            self._prof = tr._annotation(self.name, **self.attrs)
+            self._prof.__enter__()
         return self
 
+    def set(self, **attrs):
+        """Add attributes to the open span (counters known only now)."""
+        self.attrs.update(attrs)
+        if self._prof is not None:
+            self._prof.set_metadata(**attrs)
+
     def __exit__(self, *exc):
-        dur = time.monotonic() - self.t0
         if self._prof is not None:
             self._prof.__exit__(*exc)
-        self.tracer._tls.stack.pop()
-        rec = {"type": "span", "name": self.name, "ts": self.ts,
-               "dur_s": dur, "depth": self.depth, "parent": self.parent}
-        rec.update(self.attrs)
-        self.tracer._emit(rec)
+        tr = self.tracer
+        if tr.recording:
+            dur = time.monotonic() - self.t0
+            tr._tls.stack.pop()
+            rec = {"type": "span", "name": self.name, "ts": self.ts,
+                   "dur_s": dur, "depth": self.depth, "parent": self.parent}
+            rec.update(self.attrs)
+            tr._emit(rec)
         return False
 
 
 class Tracer:
-    """A span/event sink. ``path=None`` and ``echo=False`` disables it
-    entirely (``span`` returns a shared no-op context manager)."""
+    """A span/event sink. With no ``path``, no ``echo`` and no
+    ``profiler`` it is disabled entirely (``span`` returns a shared no-op
+    context manager)."""
 
     def __init__(self, path: Optional[str] = None, *, echo: bool = False,
                  profiler: bool = False):
         self.path = path
         self.echo = echo
         self.profiler = profiler
-        self._fh = None
+        self._annotation = None
+        if profiler:
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation
+        self._lines = []
         self._lock = threading.Lock()
         self._tls = threading.local()
 
     @property
-    def enabled(self) -> bool:
+    def recording(self) -> bool:
+        """Whether spans and events build records (JSONL or echo)."""
         return self.path is not None or self.echo
+
+    @property
+    def enabled(self) -> bool:
+        return self.recording or self.profiler
 
     def span(self, name: str, **attrs):
         if not self.enabled:
@@ -109,7 +137,7 @@ class Tracer:
         return _Span(self, name, attrs)
 
     def event(self, name: str, **attrs):
-        if not self.enabled:
+        if not self.recording:
             return
         stack = getattr(self._tls, "stack", [])
         rec = {"type": "event", "name": name, "ts": time.time(),
@@ -119,13 +147,9 @@ class Tracer:
         self._emit(rec)
 
     def _emit(self, rec: dict):
-        line = json.dumps(rec, default=str)
         with self._lock:
             if self.path is not None:
-                if self._fh is None:
-                    self._fh = open(self.path, "a")
-                self._fh.write(line + "\n")
-                self._fh.flush()
+                self._lines.append(json.dumps(rec, default=str))
             if self.echo:
                 ts = time.strftime("%H:%M:%S", time.localtime(rec["ts"]))
                 kv = " ".join(
@@ -134,10 +158,12 @@ class Tracer:
                 print(f"[{ts}] {rec['name']} {kv}".rstrip(), flush=True)
 
     def close(self):
+        """Append the records kept so far to ``path``."""
         with self._lock:
-            if self._fh is not None:
-                self._fh.close()
-                self._fh = None
+            if self.path is not None and self._lines:
+                with open(self.path, "a") as f:
+                    f.write("\n".join(self._lines) + "\n")
+            self._lines = []
 
 
 _TRACER = Tracer()
@@ -145,15 +171,11 @@ _TRACER = Tracer()
 
 def configure(path: Optional[str] = None, *, echo: bool = False,
               profiler: bool = False) -> Tracer:
-    """Install the process-wide tracer (and return it). Call with no
-    arguments to disable tracing again."""
+    """Install the process-wide tracer (and return it), closing the one
+    it replaces. Call with no arguments to disable tracing again."""
     global _TRACER
     _TRACER.close()
     _TRACER = Tracer(path, echo=echo, profiler=profiler)
-    return _TRACER
-
-
-def get_tracer() -> Tracer:
     return _TRACER
 
 

@@ -10,6 +10,8 @@ Contracts under test:
     tests/test_parity_matrix.py);
   * one ``pallas_call`` per step for the whole chain block and ZERO
     ``pad`` primitives inside the scan bodies (asserted on the jaxpr);
+  * the compiled executor names its layers: each ``fsgld.*`` named scope
+    is in the optimized HLO's ``op_name`` metadata;
   * ``MeshChainEngine.run`` traces ONCE for R rounds (scan-over-rounds,
     no per-round retrace or dispatch);
   * ``PackedChains`` pack/unpack round-trips exactly for any floating
@@ -18,6 +20,8 @@ Contracts under test:
   * odd-chain pad devices SKIP pad-chain gradient work
     (``make_masked_grad_vmap``, asserted on the switch branch jaxprs).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -339,6 +343,33 @@ def test_packed_run_jaxpr_single_pallas_call_no_pad_in_scan():
                 for e in _all_eqns(s.params["jaxpr"].jaxpr)]
         assert "pad" not in body, "pad op inside a scan body"
         assert body.count("pallas_call") <= 1
+
+
+@pytest.mark.parametrize("packed,scopes", [
+    (True, {"fsgld.batch", "fsgld.grad", "fsgld.pack", "fsgld.conducive",
+            "fsgld.update"}),
+    (False, {"fsgld.batch", "fsgld.grad", "fsgld.update"})])
+def test_compiled_executor_names_its_layers(packed, scopes):
+    """Every fsgld.* named scope of the round body survives compilation
+    as a component of some op's op_name in the optimized HLO: the names
+    a profiler trace attributes device time by."""
+    data, bank, theta0 = _tree_problem(jax.random.PRNGKey(2))
+    cfg = SamplerConfig(method="fsgld", step_size=1e-4, num_shards=4,
+                        local_updates=2, prior_precision=1.0,
+                        surrogate="scalar")
+    eng = MeshChainEngine(log_lik_tree, cfg, data, minibatch=6, bank=bank,
+                          use_kernel=True, packed=packed)
+    execute = eng._executor(num_rounds=1, n_chains=4,
+                            reassign="permutation", collect=False,
+                            collect_every=1, layout=eng._layout_for(theta0))
+    chains = jax.tree.map(
+        lambda t: jnp.zeros((4,) + t.shape, t.dtype), theta0)
+    hlo = execute.lower(
+        jax.random.PRNGKey(0), chains, data, bank,
+        jnp.asarray(0, jnp.int32), None, None).compile().as_text()
+    found = {part for name in re.findall(r'op_name="([^"]*)"', hlo)
+             for part in name.split("/") if part.startswith("fsgld.")}
+    assert found == scopes
 
 
 def test_packed_float_only_guard():
