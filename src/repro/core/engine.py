@@ -1482,7 +1482,8 @@ class MeshChainEngine:
 
         Host spans (``repro.obs.trace``): ``engine.run`` around the call
         (``executor_built``: executors built, i.e. cache misses), and in
-        it ``engine.layout`` (resolving the packed layout),
+        it ``engine.layout`` (resolving the packed layout; ``block_rows``
+        and ``grid_steps``: the kernel's block height and grid length),
         ``engine.stage`` (copying and placing the chain state, setting
         up the carries; ``bytes``: the chain state staged) and one
         ``engine.segment`` per executor dispatch.
@@ -1567,10 +1568,13 @@ class MeshChainEngine:
         # the packed layout is built from the PARAMETER pytree alone: the
         # sghmc momenta share its structure (and hence its packed layout)
         ex_theta = theta0[0] if self.dynamics == "sghmc" else theta0
-        with obs_trace.span("engine.layout"):
+        with obs_trace.span("engine.layout") as lay:
             layout = self._layout_for(
                 jax.tree.map(lambda t: t[0], ex_theta) if stacked
                 else ex_theta)
+            if layout is not None:
+                lay.set(block_rows=layout.block_rows,
+                        grid_steps=n_total * sum(layout.leaf_blocks))
         with obs_trace.span("engine.stage") as stage:
             cshard = NamedSharding(self.mesh, self._chain_spec())
             if stacked:

@@ -45,7 +45,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 BLOCK_ROWS = 256  # 256 x 128 fp32 = 128 KiB per operand tile in VMEM
-PACK_BLOCK_ROWS = 8  # packed multi-leaf grid: fp32 min tile, small pad waste
+PACK_BLOCK_ROWS = 8  # packed multi-leaf grid: fp32 min tile, the floor
+# of ops.choose_block_rows; its cap: the body's (block_rows, 128)
+# temporaries live in VMEM, which 2048 rows overflow on a v5e
+PACK_BLOCK_ROWS_MAX = 512
 
 # scalar-operand layout (one f32 row broadcast to every block of a
 # (chain, leaf)); S_FRIC is the SGHMC friction alpha_f, dead for langevin

@@ -25,7 +25,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.fsgld_update import (LANE, PACK_BLOCK_ROWS, SCALAR_COLS,
+from repro.kernels.fsgld_update import (LANE, PACK_BLOCK_ROWS,
+                                        PACK_BLOCK_ROWS_MAX, SCALAR_COLS,
                                         fsgld_update_2d, fsgld_update_packed)
 
 PyTree = Any
@@ -356,16 +357,40 @@ class PackedChains:
         return flat.reshape(buf.shape)
 
 
+def _leaf_rows(sizes, block_rows: int) -> tuple:
+    """Rows each leaf owns when padded to whole (block_rows, 128) blocks."""
+    per_block = block_rows * LANE
+    return tuple(-(-n // per_block) * block_rows for n in sizes)
+
+
+def choose_block_rows(sizes) -> int:
+    """The packed grid's block height for leaves of ``sizes`` elements: the
+    largest power of two from PACK_BLOCK_ROWS to PACK_BLOCK_ROWS_MAX whose
+    leaf padding adds at most 1/256 of the rows the PACK_BLOCK_ROWS layout
+    holds. Every grid step pays a fixed cost (~0.35 us on a v5e), so a tree
+    of large leaves wants tall blocks; a tree of small, ragged leaves
+    would pad into a larger buffer, and keeps short ones."""
+    floor = sum(_leaf_rows(sizes, PACK_BLOCK_ROWS))
+    best, b = PACK_BLOCK_ROWS, PACK_BLOCK_ROWS
+    while b < PACK_BLOCK_ROWS_MAX:
+        b *= 2
+        if (sum(_leaf_rows(sizes, b)) - floor) * 256 <= floor:
+            best = b
+    return best
+
+
 def make_packed_layout(theta: PyTree,
-                       block_rows: int = PACK_BLOCK_ROWS) -> PackedChains:
+                       block_rows: Optional[int] = None) -> PackedChains:
     """Build the packed layout from a SINGLE-chain example pytree (shapes
-    without the leading chain axis)."""
+    without the leading chain axis). ``block_rows`` defaults to
+    ``choose_block_rows`` of the leaf sizes."""
     leaves, treedef = jax.tree.flatten(theta)
     shapes = tuple(tuple(l.shape) for l in leaves)
     dtypes = tuple(l.dtype for l in leaves)
     sizes = tuple(int(l.size) for l in leaves)
-    per_block = block_rows * LANE
-    rows = tuple(-(-n // per_block) * block_rows for n in sizes)
+    if block_rows is None:
+        block_rows = choose_block_rows(sizes)
+    rows = _leaf_rows(sizes, block_rows)
     row_offsets, acc = [], 0
     for r in rows:
         row_offsets.append(acc)

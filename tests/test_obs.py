@@ -235,6 +235,10 @@ def test_engine_run_spans_nest_and_count(tmp_path):
     assert all(r["rounds"] == 1 for r in runs)
     stages = [r for r in recs if r["name"] == "engine.stage"]
     assert [r["bytes"] for r in stages] == [state.nbytes] * 2 == [24] * 2
+    # a (3,) leaf: one 8-row block per chain, two chains
+    layouts = [r for r in recs if r["name"] == "engine.layout"]
+    assert [(r["block_rows"], r["grid_steps"]) for r in layouts] == \
+        [(8, 2)] * 2
 
 
 # ---------------------------------------------------------------------------

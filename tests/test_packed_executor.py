@@ -8,6 +8,10 @@ Contracts under test:
     and SGHMC with the second momentum buffer), multi-leaf pytrees, and
     ragged shards (the full executor x dynamics x dtype grid lives in
     tests/test_parity_matrix.py);
+  * the block height comes from the leaf shapes (``choose_block_rows``:
+    512 rows at the benchmark configurations' widths, 8 for small ragged
+    leaves, padding at most 1/256), and a step's state does not depend
+    on it;
   * one ``pallas_call`` per step for the whole chain block and ZERO
     ``pad`` primitives inside the scan bodies (asserted on the jaxpr);
   * the compiled executor names its layers: each ``fsgld.*`` named scope
@@ -20,6 +24,7 @@ Contracts under test:
   * odd-chain pad devices SKIP pad-chain gradient work
     (``make_masked_grad_vmap``, asserted on the switch branch jaxprs).
 """
+import dataclasses
 import re
 
 import jax
@@ -28,11 +33,13 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.configs import get_config
 from repro.configs.base import SamplerConfig
 from repro.core import (FederatedSampler, MeshChainEngine, make_bank,
                         pad_shards, analytic_gaussian_likelihood_surrogate)
 from repro.core.engine import pack_bank, resident_surrogates
 from repro.kernels import ops
+from repro.models import init_params
 
 
 # ---------------------------------------------------------------------------
@@ -89,15 +96,17 @@ def _ragged_problem(key, S=5, d=3):
 
 @pytest.mark.parametrize("dynamics", ["langevin", "sghmc"])
 @pytest.mark.parametrize("variant", ["plain", "scalar"])
-def test_packed_step_bitmatches_per_leaf_kernel_multileaf(variant,
+@pytest.mark.parametrize("block_rows", [8, 32])
+def test_packed_step_bitmatches_per_leaf_kernel_multileaf(block_rows,
+                                                          variant,
                                                           dynamics):
     key = jax.random.PRNGKey(0)
     C, S = 4, 5
-    # "b" spans MULTIPLE packed blocks (2*1300 > 2 * block_rows*LANE =
-    # 2048): a non-zero in-leaf block offset and a leaf spanning several
-    # blocks — the paths a single-block leaf never touches — are
-    # exercised here
-    shapes = {"a": (37,), "b": (2, 1300), "c": (3,)}
+    # "b" spans MULTIPLE packed blocks at both heights (10000 > 2 *
+    # 32*LANE = 8192): a non-zero in-leaf block offset and a leaf
+    # spanning several blocks — the paths a single-block leaf never
+    # touches — are exercised here
+    shapes = {"a": (37,), "b": (2, 5000), "c": (3,)}
     ks = jax.random.split(key, 10)
     theta = {n: jax.random.normal(jax.random.fold_in(ks[0], i), (C,) + s)
              for i, (n, s) in enumerate(shapes.items())}
@@ -131,7 +140,9 @@ def test_packed_step_bitmatches_per_leaf_kernel_multileaf(variant,
     if hmc:
         ref, ref_r = ref
 
-    layout = ops.make_packed_layout(jax.tree.map(lambda t: t[0], theta))
+    layout = ops.make_packed_layout(jax.tree.map(lambda t: t[0], theta),
+                                    block_rows=block_rows)
+    assert layout.leaf_blocks[1] > 1
     th_p = layout.pack(theta)
     g_p = layout.pack(g)
     seeds = ops.chain_leaf_seeds(keys, layout.num_leaves)
@@ -195,6 +206,114 @@ def test_packed_step_bitmatches_per_leaf_kernel_diag():
         lam_s=lam_s)
     np.testing.assert_array_equal(np.asarray(layout.unpack(out_p)),
                                   np.asarray(ref))
+
+
+def _packed_state_at(block_rows, variant, dynamics, tree):
+    """Leaves after one ``packed_step`` at a given block height (and the
+    storage-dtype round trip), from fixed inputs: the surrogate operands
+    are packed straight from trees, so every variant's drift runs."""
+    C = 3
+    ks = jax.random.split(jax.random.PRNGKey(11), 8)
+
+    def draw(k, lead=(C,)):
+        return {n: jax.random.normal(jax.random.fold_in(k, i),
+                                     lead + s.shape).astype(s.dtype)
+                for i, (n, s) in enumerate(tree.items())}
+
+    layout = ops.make_packed_layout(tree, block_rows=block_rows)
+    L = layout.num_leaves
+    kw = {}
+    if variant != "plain":
+        kw["mu_g"] = layout.pack_shared(draw(ks[2], ()))
+        kw["mu_s"] = layout.pack(draw(ks[3]))
+    if variant == "diag":
+        kw["lam_g"] = jnp.abs(layout.pack_shared(draw(ks[4], ())))
+        kw["lam_s"] = jnp.abs(layout.pack(draw(ks[5])))
+    if dynamics == "sghmc":
+        kw["r_p"] = 0.01 * layout.pack(draw(ks[6]))
+    scalars = ops.packed_scalar_rows(
+        layout, h=1e-3, scale=jnp.linspace(5.0, 20.0, C),
+        f_s=jnp.linspace(0.2, 0.5, C), prior_prec=1.0, alpha=1.0,
+        temperature=1.0, lam_g_leaf=jnp.linspace(1.0, 2.0, L),
+        lam_s_leaf=jnp.linspace(0.5, 1.5, C * L).reshape(C, L),
+        friction=0.25 if dynamics == "sghmc" else 0.0)
+    out = ops.packed_step(
+        layout, layout.pack(draw(ks[0])), layout.pack(draw(ks[1])),
+        ops.chain_leaf_seeds(jax.random.split(ks[7], C), L), scalars,
+        variant=variant, dynamics=dynamics, **kw)
+    outs = out if dynamics == "sghmc" else (out,)
+    return [layout.unpack(layout.quantize(o)) for o in outs]
+
+
+@pytest.mark.parametrize("dynamics", ["langevin", "sghmc"])
+@pytest.mark.parametrize("variant", ["plain", "scalar", "diag"])
+def test_packed_state_does_not_depend_on_block_height(variant, dynamics):
+    """The same step at 8- and 32-row blocks gives equal leaves: live
+    elements keep their (chain, leaf) seed and in-leaf index, only the
+    pad at each leaf's tail grows. A bf16 leaf rides the quantize path."""
+    tree = {"a": jax.ShapeDtypeStruct((37,), jnp.float32),
+            "b": jax.ShapeDtypeStruct((3, 3000), jnp.float32),
+            "c": jax.ShapeDtypeStruct((5000,), jnp.bfloat16)}
+    lo = _packed_state_at(8, variant, dynamics, tree)
+    hi = _packed_state_at(32, variant, dynamics, tree)
+    for got_lo, got_hi in zip(lo, hi):
+        for n in tree:
+            assert got_hi[n].dtype == tree[n].dtype
+            np.testing.assert_array_equal(np.asarray(got_lo[n]),
+                                          np.asarray(got_hi[n]), err_msg=n)
+
+
+# ---------------------------------------------------------------------------
+# the block rule: block height from the leaf shapes
+# ---------------------------------------------------------------------------
+
+def _rows_at(sizes, block_rows):
+    return sum(-(-n // (block_rows * 128)) * block_rows for n in sizes)
+
+
+def test_block_rule_keeps_small_ragged_leaves_at_the_floor():
+    """At 16 rows this tree would pad 60% more rows: it keeps 8."""
+    tree = {"a": jnp.zeros(37), "b": jnp.zeros((2, 1300)),
+            "c": jnp.zeros(3)}
+    assert ops.make_packed_layout(tree).block_rows == 8
+    assert _rows_at([37, 2600, 3], 16) > 1.5 * _rows_at([37, 2600, 3], 8)
+
+
+def _benchmark_arch(name):
+    if name == "h2o-danube-1.8b-2L":
+        return dataclasses.replace(get_config("h2o-danube-1.8b"),
+                                   num_layers=2)
+    # RWKV-6 Finch 1.6B's widths on the registry's RWKV-6 family
+    return dataclasses.replace(get_config("rwkv6-7b"), num_layers=1,
+                               d_model=2048, num_heads=32, num_kv_heads=32,
+                               head_dim=64, d_ff=7168)
+
+
+@pytest.mark.parametrize("name,params,grid", [
+    ("h2o-danube-1.8b-2L", 302_789_120, 4_623),
+    ("rwkv6-1.6b-1L", 329_533_440, 5_037)])
+def test_block_rule_picks_512_at_benchmark_widths(name, params, grid):
+    """Shapes only (``jax.eval_shape``): no weights, no compile."""
+    cfg = _benchmark_arch(name)
+    tree = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    sizes = [int(l.size) for l in jax.tree.leaves(tree)]
+    assert sum(sizes) == params
+    layout = ops.make_packed_layout(tree)
+    assert layout.block_rows == 512
+    assert sum(layout.leaf_blocks) == grid
+    assert layout.rows_total * 256 <= _rows_at(sizes, 8) * 257
+
+
+@settings(max_examples=60, deadline=None)
+@given(st.lists(st.integers(1, 3 * 10**7), min_size=1, max_size=24))
+def test_block_rule_pads_at_most_1_in_256(sizes):
+    b = ops.choose_block_rows(sizes)
+    floor = _rows_at(sizes, 8)
+    assert b in (8, 16, 32, 64, 128, 256, 512)
+    assert (_rows_at(sizes, b) - floor) * 256 <= floor
+    # the largest such height up to the cap
+    taller = [2 * b * 2**k for k in range(8) if 2 * b * 2**k <= 512]
+    assert all((_rows_at(sizes, t) - floor) * 256 > floor for t in taller)
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,8 @@ Nothing runs: the TPU compiler (installed with JAX) lowers the Pallas
 kernels with ``interpret=False`` for a chip that is described, not
 attached, and refuses what Mosaic would refuse on the chip (unaligned
 blocks, SMEM or VMEM overflow, casts it cannot lower). Shapes are the
-real ones: h2o-danube-1.8b's leaves at their published widths.
+real ones: h2o-danube-1.8b's leaves, and rwkv6-1.6b's vocabulary-wide
+embedding and head, at their published widths.
 
 The topology is described inside a module fixture (never on import): only
 one process at a time may load the TPU library, and it is this file's
@@ -18,7 +19,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.fsgld_update import (LANE, SCALAR_COLS, fsgld_update_2d,
+from repro.kernels.fsgld_update import (LANE, PACK_BLOCK_ROWS_MAX,
+                                        SCALAR_COLS, fsgld_update_2d,
                                         fsgld_update_packed)
 from repro.kernels.ops import make_packed_layout
 from repro.models import init_params
@@ -86,7 +88,8 @@ def test_per_leaf_kernel_compiles_for_v5e(one_chip, chains):
                          [("scalar", "langevin"), ("plain", "sghmc")])
 def test_packed_kernel_compiles_for_v5e(one_chip, variant, dynamics):
     """Every leaf of one full-width h2o-danube layer, 2 chains, one
-    pallas_call at the packed layout (PACK_BLOCK_ROWS rows per block)."""
+    pallas_call at the packed layout (the block height
+    ``choose_block_rows`` picks from the leaf shapes: 512 rows)."""
     def sds(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -95,6 +98,7 @@ def test_packed_kernel_compiles_for_v5e(one_chip, variant, dynamics):
     layer = jax.eval_shape(
         lambda: init_params(one, jax.random.PRNGKey(0)))["blocks"]
     layout = make_packed_layout(layer)
+    assert layout.block_rows == PACK_BLOCK_ROWS_MAX
     rows = C * layout.rows_total
     kw = _operands(variant, dynamics, sds, rows, layout.rows_total)
 
@@ -107,3 +111,32 @@ def test_packed_kernel_compiles_for_v5e(one_chip, variant, dynamics):
     _compile(fn, (sds((rows, LANE)), sds((rows, LANE)),
                   sds((C, layout.num_leaves), jnp.uint32),
                   sds((C, layout.num_leaves, SCALAR_COLS)), kw))
+
+
+@pytest.mark.parametrize("variant,dynamics",
+                         [("scalar", "langevin"), ("diag", "sghmc")])
+def test_packed_kernel_compiles_for_v5e_at_rwkv_vocab_leaves(
+        one_chip, variant, dynamics):
+    """rwkv6-1.6b's (65536, 2048) embedding and (2048, 65536) head, one
+    chain, at the block height the rule picks for them; ``diag``/``sghmc``
+    holds the most operands, so a body that outgrows VMEM fails here."""
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    d_model, vocab = 2048, 65536
+    leaves = {"embed": jax.ShapeDtypeStruct((vocab, d_model), jnp.float32),
+              "head": jax.ShapeDtypeStruct((d_model, vocab), jnp.float32)}
+    layout = make_packed_layout(leaves)
+    assert layout.block_rows == PACK_BLOCK_ROWS_MAX
+    rows = layout.rows_total
+    kw = _operands(variant, dynamics, sds, rows, rows)
+
+    def fn(th, g, seeds, sc, kw):
+        return fsgld_update_packed(
+            th, g, seeds, sc, variant=variant, dynamics=dynamics,
+            leaf_blocks=layout.leaf_blocks, block_rows=layout.block_rows,
+            interpret=False, **kw)
+
+    _compile(fn, (sds((rows, LANE)), sds((rows, LANE)),
+                  sds((1, layout.num_leaves), jnp.uint32),
+                  sds((1, layout.num_leaves, SCALAR_COLS)), kw))
