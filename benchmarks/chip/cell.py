@@ -19,6 +19,8 @@ import math
 import pathlib
 import sys
 import time
+import types
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -52,16 +54,57 @@ def load(workload: str) -> dict:
             "traffic": traffic, "limits": limits}
 
 
+class Frozen(dict):
+    """A mapping that hashes by its items, so that a configuration holding
+    one is a ``jit`` static argument; built once and never changed."""
+
+    def __hash__(self):
+        return hash(tuple(sorted(self.items())))
+
+
+def freeze(value):
+    """``value`` with every mapping made ``Frozen`` and every list a tuple,
+    all the way down."""
+    if isinstance(value, dict):
+        return Frozen((k, freeze(v)) for k, v in value.items())
+    if isinstance(value, (list, tuple)):
+        return tuple(freeze(v) for v in value)
+    return value
+
+
 def arch_of(config: dict) -> dict:
-    """The reference's view of a configuration: its shapes, and the one
-    layer kind its pattern repeats."""
-    a = dict(config["arch"])
-    kinds = set(a["layer_pattern"])
-    if len(kinds) != 1:
-        raise ValueError(f"one layer kind per configuration, got {kinds}")
-    a["layer_kind"] = kinds.pop()
-    a["layer_pattern"] = tuple(a["layer_pattern"])
-    return a
+    """The reference's view of a configuration: its ``arch``, frozen (its
+    ``layer_pattern`` a tuple of layer kinds, any number of them)."""
+    return dict(freeze(config["arch"]))
+
+
+def from_json(cls, value: dict):
+    """The dataclass ``cls`` from a JSON object: each field converted to
+    the type it is annotated with (a nested dataclass from an object, a
+    tuple from a list, through ``Optional``); keys that are not fields
+    are dropped."""
+    hints = typing.get_type_hints(cls)
+    names = {f.name for f in dataclasses.fields(cls)}
+    return cls(**{k: _typed(hints[k], v) for k, v in value.items()
+                  if k in names})
+
+
+def _typed(hint, value):
+    if typing.get_origin(hint) in (typing.Union, types.UnionType):
+        args = [a for a in typing.get_args(hint) if a is not type(None)]
+        if len(args) == 1:
+            hint = args[0]
+    if isinstance(value, dict) and dataclasses.is_dataclass(hint):
+        return from_json(hint, value)
+    if isinstance(value, list):
+        return tuple(value)
+    return value
+
+
+def program_config(arch: dict):
+    """The program's ``ArchConfig`` of a configuration's JSON ``arch``."""
+    from repro.configs.base import ArchConfig
+    return from_json(ArchConfig, arch)
 
 
 def seed_key(seed: int):
@@ -192,15 +235,11 @@ class Cell:
         """The system under test: an FSGLD sampler on the packed executor
         over the cell's clients, with the bank above."""
         from repro import api
-        from repro.configs.base import ArchConfig
         from repro.core.surrogate import Gaussian, SurrogateBank
         from repro.launch.mesh import make_sim_mesh
         from repro.models import log_lik_fn
 
-        fields = {f.name for f in dataclasses.fields(ArchConfig)}
-        cfg = ArchConfig(**{k: (tuple(v) if isinstance(v, list) else v)
-                            for k, v in self.config["arch"].items()
-                            if k in fields})
+        cfg = program_config(self.config["arch"])
         means, lam_s, mu_g, lam_g = self.bank
         td = jax.tree.structure(mu_g)
         L = td.num_leaves

@@ -6,6 +6,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+import refmodel
+
 PARAM_KEY = "attn"
 
 
@@ -41,13 +43,9 @@ def forward(h, p, arch, pr):
     # query head i reads kv head i // (H / K)
     k = jnp.repeat(k, H // K, axis=2)
     v = jnp.repeat(v, H // K, axis=2)
-    scores = pr.einsum("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
-    pos = jnp.arange(s)
-    keep = (pos[None, :] <= pos[:, None]) & \
-        (pos[None, :] > pos[:, None] - arch["swa_window"])
-    scores = jnp.where(keep, scores, -jnp.inf)
-    att = jax.nn.softmax(scores, axis=-1)
-    o = pr.einsum("bhqk,bkhd->bqhd", att, v).astype(pr.dtype)
+    w = arch["swa_window"]
+    o = refmodel.attention(
+        q, k, v, lambda qp, kp: (kp <= qp) & (kp > qp - w), pr)
     return pr.mm(o.reshape(b, s, H * hd), p["wo"])
 
 

@@ -11,12 +11,19 @@ sys.path.insert(0, str(HERE.parents[2] / "src"))
 sys.path.insert(0, str(HERE.parent))
 
 
-def tiny_spec(kind: str = "swa", minibatch: int = 2) -> dict:
-    """A cell at a size the CPU holds: same code paths, toy widths."""
-    arch = {"name": f"tiny-{kind}", "family": "dense" if kind == "swa"
-            else "ssm", "num_layers": 2, "d_model": 64, "num_heads": 4,
-            "num_kv_heads": 2 if kind == "swa" else 4, "head_dim": 16,
-            "d_ff": 128, "vocab_size": 256, "layer_pattern": [kind],
+def tiny_spec(pattern: str = "swa", minibatch: int = 2) -> dict:
+    """A cell at a size the CPU holds: same code paths, toy widths.
+    ``pattern``: the layer kinds of one period, joined by ``+``; the stack
+    is one whole period and one layer more (two layers of a one-kind
+    pattern)."""
+    kinds = pattern.split("+")
+    family = {("swa",): "dense", ("rwkv",): "ssm"}.get(tuple(kinds),
+                                                      "hybrid")
+    name = f"tiny-{pattern}"
+    arch = {"name": name, "family": family,
+            "num_layers": len(kinds) + 1, "d_model": 64, "num_heads": 4,
+            "num_kv_heads": 2 if "swa" in kinds else 4, "head_dim": 16,
+            "d_ff": 128, "vocab_size": 256, "layer_pattern": kinds,
             "swa_window": 48, "rope_theta": 10000.0, "ffn_type": "silu",
             "param_dtype": "float32", "surrogate_dtype": "bfloat16",
             "source": "test"}
@@ -27,10 +34,10 @@ def tiny_spec(kind: str = "swa", minibatch: int = 2) -> dict:
                "clients": 4, "sequences_per_client": 16, "seq_len": 64,
                "dirichlet_alpha": 0.1, "minibatch": minibatch,
                "local_steps": 2, "fit": {"burn": 2, "minibatch": minibatch}}
-    cell = {"name": f"tiny-{kind}", "config": f"tiny-{kind}",
+    cell = {"name": name, "config": name,
             "traffic": "tiny", "chips": 1}
     return {"bench": {"workloads": [cell], "end_to_end": [], "per_layer": []},
-            "cell": cell, "config": {"name": f"tiny-{kind}", "arch": arch},
+            "cell": cell, "config": {"name": name, "arch": arch},
             "traffic": traffic,
             "limits": {"grad_gap": 1.0, "change_gap": 1.0,
                        "state_gap": 1.0}}

@@ -23,18 +23,17 @@ def leaves(a):
     return jax.tree.leaves(shapes)
 
 
-@pytest.mark.parametrize("kind", ["swa", "rwkv"])
+@pytest.mark.parametrize("kind", ["swa", "rwkv", "swa+rwkv"])
 def test_param_count_matches_the_reference_layout(kind):
     a = cell.arch_of(tiny_spec(kind)["config"])
     assert counts.param_count(a) == sum(l.size for l in leaves(a))
 
 
-@pytest.mark.parametrize("kind", ["swa", "rwkv"])
+@pytest.mark.parametrize("kind", ["swa", "rwkv", "swa+rwkv"])
 def test_reference_layout_is_the_programs(kind):
-    from repro.configs.base import ArchConfig
     from repro.models import init_params
     spec = tiny_spec(kind)["config"]["arch"]
-    cfg = ArchConfig(**{**spec, "layer_pattern": tuple(spec["layer_pattern"])})
+    cfg = cell.program_config(spec)
     prog = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
     ref = jax.eval_shape(lambda k: refmodel.init_params(
         cell.arch_of({"arch": spec}), k), jax.random.PRNGKey(0))
@@ -46,7 +45,7 @@ def test_reference_layout_is_the_programs(kind):
 def test_hand_count_at_a_tiny_swa_config():
     a = {"d_model": 8, "d_ff": 16, "vocab_size": 10, "num_layers": 2,
          "num_heads": 2, "num_kv_heads": 1, "head_dim": 4,
-         "swa_window": 3, "layer_kind": "swa"}
+         "swa_window": 3, "layer_pattern": ["swa"]}
     # per layer: wq 8x8, wk 8x4, wv 8x4, wo 8x8 = 192; ffn 3 x 8x16 = 384
     assert counts.matmul_params(a) == 8 * 10 + 2 * (192 + 384)
     assert counts.param_count(a) == 2 * 80 + 8 + 2 * (192 + 384 + 16)
@@ -60,7 +59,7 @@ def test_hand_count_at_a_tiny_swa_config():
 def test_hand_count_at_a_tiny_rwkv_config():
     a = {"d_model": 8, "d_ff": 16, "vocab_size": 10, "num_layers": 1,
          "num_heads": 2, "num_kv_heads": 2, "head_dim": 4,
-         "layer_kind": "rwkv"}
+         "layer_pattern": ["rwkv"]}
     mix = 4 * 8 * 8 + 2 * 8 * 64          # r, k, v, o; the rank-64 decay
     assert counts.matmul_params(a) == 8 * 10 + 3 * 8 * 16 + mix
     assert counts.param_count(a) == 2 * 80 + 8 + 3 * 8 * 16 + 16 + mix \
@@ -95,3 +94,18 @@ def test_missing_device_is_an_error():
     assert counts.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         counts.peaks("TPU v9 imaginary")
+
+
+# recorded from the counts of one layer kind, before they summed plug-ins
+# over the layers of a pattern
+PINNED = [("h2o-danube-1.8b-2L", 4, 5556739768320.0, 4844625920),
+          ("rwkv6-1.6b-1L", 4, 4809390292992.0, 5272535040),
+          ("rwkv6-1.6b-1L", 1, 1202347573248.0, 5272535040)]
+
+
+@pytest.mark.parametrize("name,batch,flops,update", PINNED)
+def test_counts_of_the_benchmark_cells_are_unchanged(name, batch, flops,
+                                                     update):
+    a = arch(name)
+    assert counts.step_flops(a, batch, 1024) == flops
+    assert counts.update_bytes(a) == update

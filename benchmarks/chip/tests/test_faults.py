@@ -37,8 +37,10 @@ def run_tiny(kind, workload, seconds=0.5):
     return res.checks, checks.judge(res.checks, c.limits)
 
 
+# the last: a pattern of two kinds, three layers (a whole period and one
+# layer past it), judged by the tightest state limit of the three
 CASES = [("swa", "danube-2L.b4x1024"), ("rwkv", "rwkv6-1L.b4x1024"),
-         ("rwkv", "rwkv6-1L.b1x1024")]
+         ("rwkv", "rwkv6-1L.b1x1024"), ("swa+rwkv", "rwkv6-1L.b4x1024")]
 
 
 @pytest.mark.parametrize("kind,workload", CASES)
